@@ -12,6 +12,8 @@ Two regimes:
 * ``shard-{S}slab-hostdev`` — a subprocess under
   ``XLA_FLAGS=--xla_force_host_platform_device_count=8``: S slabs on 8
   forced host devices vs the single-device session in the same process.
+  The child runs with ``JAX_PLATFORMS=cpu``: the parent already holds
+  any accelerator (a chip belongs to one process at a time).
   On one physical CPU the forced devices time-slice, so this measures the
   *scaling structure* (per-slab work shrinks with S, communication is
   O(surface)) rather than real speedup — the ratio is the tracked
@@ -80,7 +82,8 @@ def _run_case(n, steps, n_slabs, radius, k, devices):
     env = {**os.environ,
            "PYTHONPATH": os.path.join(os.path.dirname(__file__), "..",
                                       "src"),
-           "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}"}
+           "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
+           "JAX_PLATFORMS": "cpu"}
     args = json.dumps([n, steps, n_slabs, radius, k])
     r = subprocess.run([sys.executable, "-c", _WORKER, args], env=env,
                        capture_output=True, text=True, timeout=1800)

@@ -9,8 +9,8 @@ to ``BENCH_executor.json`` at the repo root so the perf trajectory
 accumulates across PRs. The ``pallas_traced`` column times
 ``jax.jit(api.query)`` with ``SearchOpts(use_pallas=True)`` — the
 level-segmented fused-kernel schedule as ONE compiled program (DESIGN.md
-section 3); on this CPU container the kernels run in interpret mode, so
-that column measures orchestration structure, not kernel speed.
+section 3); it runs only off-TPU, where the kernels run in interpret
+mode, so that column measures orchestration structure, not kernel speed.
 
 ``REPRO_BENCH_SMOKE=1`` shrinks the workloads for CI (scripts/ci.sh).
 """
@@ -97,28 +97,27 @@ def run(k=8):
         st = ns_new.executor.stats()
 
         # single-program traced Pallas pipeline: jit(api.query), the whole
-        # schedule->anchor->gather->knn as one compiled program. Interpret
-        # mode emulates the kernels in Python, so on CPU containers the
-        # column is only affordable on the stream-sized cases; compiled
-        # TPU runs (PALLAS_INTERPRET=0) measure every case.
-        from repro.kernels.ops import INTERPRET
+        # schedule->anchor->gather->knn as one compiled program. It exists
+        # only off-TPU (build_index refuses it on a TPU backend), where
+        # interpret mode emulates the kernels in Python, so the column is
+        # only affordable on the stream-sized cases.
         t_tr = None
-        if not INTERPRET or nq <= 1024:
+        if jax.default_backend() != "tpu" and nq <= 1024:
             index_p = api.build_index(pts, params,
                                       SearchOpts(use_pallas=True,
                                                  query_tile=tile))
             traced = jax.jit(api.query)
             qs_dev = np.asarray(qs, np.float32)
             res_tr = traced(index_p, qs_dev)             # warm compile
-            # distances/counts are bitwise across the fused and jnp paths
-            # (indices only up to ties) — hold the timed column to that
+            # counts are exact across the fused and jnp paths; distances
+            # agree to f32 rounding (the two expand |q-p|^2 differently)
             assert np.array_equal(np.asarray(res_tr.counts),
                                   np.asarray(res_new.counts))
             d_tr = np.where(np.isinf(np.asarray(res_tr.distances2)), -1.0,
                             np.asarray(res_tr.distances2))
             d_ex = np.where(np.isinf(np.asarray(res_new.distances2)), -1.0,
                             np.asarray(res_new.distances2))
-            assert np.array_equal(d_tr, d_ex)
+            np.testing.assert_allclose(d_tr, d_ex, rtol=0, atol=1e-6)
             _, t_tr = _paired_timeit(lambda: ns_new.query(qs),
                                      lambda: traced(index_p, qs_dev),
                                      repeats=3 if SMOKE else 7)

@@ -52,6 +52,8 @@ def _print_summary(rows: list[tuple]) -> None:
 
 
 def main() -> None:
+    from repro.launch.cache import configure_compile_cache
+    configure_compile_cache()
     from . import (fig05_coherence, fig07_aabb_width, fig11_speedup,
                    fig12_breakdown, fig13_ablation, fig14_sensitivity,
                    fig15_build_time, fig16_partition_dist, fig_batch,

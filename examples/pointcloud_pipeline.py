@@ -17,6 +17,7 @@ import numpy as np
 from repro.core import NeighborSearch, SearchOpts, SearchParams
 from repro.data.pointclouds import kitti_like_cloud
 from repro.kernels.ref import brute_force_search
+from repro.launch.cache import configure_compile_cache
 
 K = 16
 R = 0.03
@@ -36,6 +37,7 @@ def estimate_normals(points, nbr_idx):
 
 
 def main():
+    configure_compile_cache()
     pts = kitti_like_cloud(60_000, seed=3)
     t0 = time.perf_counter()
     ns = NeighborSearch(pts, SearchParams(radius=R, k=K))

@@ -8,7 +8,9 @@ import numpy as np
 
 from repro import api
 from repro.api import SearchParams
+from repro.launch.cache import configure_compile_cache
 
+configure_compile_cache()
 rng = np.random.default_rng(0)
 points = rng.random((50_000, 3)).astype(np.float32)   # your point cloud
 queries = rng.random((5_000, 3)).astype(np.float32)   # where to search

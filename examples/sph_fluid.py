@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.core import (NeighborSearch, SearchOpts, SearchParams,
                         SimulationSession)
+from repro.launch.cache import configure_compile_cache
 
 H = 0.06            # smoothing radius
 K_MAX = 32          # bounded neighbor count (the paper's K)
@@ -106,6 +107,7 @@ def step_session(sess, pos, vel):
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--particles", type=int, default=8000)
     ap.add_argument("--steps", type=int, default=5)

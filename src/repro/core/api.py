@@ -165,7 +165,14 @@ def build_index(points, params: SearchParams,
     at the padding sentinel are dropped from the grid entirely instead of
     binned into the clamped corner cell (which would pollute megacell
     counts near the grid's high corner).
+
+    ``SearchOpts(use_pallas=True)`` raises ``NotImplementedError`` on a
+    TPU backend, where the fused kernel's in-kernel gathers do not compile
+    (``kernels/ops.check_fused_path``).
     """
+    if opts.use_pallas:
+        from ..kernels.ops import check_fused_path
+        check_fused_path()
     if spec is None:
         if isinstance(points, jax.core.Tracer):
             raise TypeError(

@@ -115,11 +115,18 @@ def plan_bundles(
     """Paper appendix C: sort by query count ascending; for each candidate
     bundle count M0, merge the (M - M0 + 1) smallest-N partitions, keep the
     rest separate; return the argmin-cost strategy. ``enable=False`` is the
-    paper's Listing-3 default (one bundle per partition)."""
+    paper's Listing-3 default (one bundle per partition).
+
+    Equal query counts are ordered by window descending: under the inverse
+    correlation the wider window stands on the small-N side, so it is the
+    one merged first (merging the narrower ones first can miss the
+    optimum, e.g. three single-query partitions at windows 7, 8, 9)."""
     m = len(parts)
     if m == 0:
         return []
-    order = sorted(range(m), key=lambda i: parts[i].count)   # N ascending
+    # N ascending, ties by window descending
+    order = sorted(range(m), key=lambda i: (parts[i].count,
+                                            -parts[i].w_search))
     if not enable or m == 1:
         return [_mk_bundle(parts, (i,), w_sph) for i in range(m)]
 

@@ -9,11 +9,6 @@ functional core entirely. All of that now lives — traced — in
 halo exchange via ``ppermute`` inside ``shard_map(api.query)``, one shared
 static ``GridSpec`` across slabs, and the traced inverse scatter. This
 module keeps the legacy one-shot convenience surface.
-
-Version compatibility (shard_map location, ``check_rep``/``check_vma``)
-is feature-detected in ``shards.py``; ``_shard_map``/``_SHARD_MAP_KW``
-are re-exported here for callers that historically imported them from
-this module.
 """
 from __future__ import annotations
 
@@ -21,8 +16,7 @@ import dataclasses
 
 from jax.sharding import Mesh
 
-from .shards import (_SHARD_MAP_KW, _shard_map,  # noqa: F401 (re-export)
-                     STATIC_SCENE_OPTS, shard_scene)
+from .shards import STATIC_SCENE_OPTS, shard_scene
 from .types import SearchOpts, SearchParams, SearchResult
 
 

@@ -474,19 +474,12 @@ class QueryExecutor:
         signature); ``jit_cache_sizes`` exposes the actual jit caches so
         tests can assert a steady-state query compiled nothing.
         """
-        sizes = {}
-        try:
-            from .search import window_search
-            sizes["window_search"] = window_search._cache_size()
-        except AttributeError:                      # pragma: no cover
-            pass
+        from .search import window_search
+        sizes = {"window_search": window_search._cache_size()}
         if self.ns.opts.use_pallas:
-            try:
-                from ..kernels.knn_tile import knn_tile, knn_tile_anchored
-                sizes["knn_tile"] = knn_tile._cache_size()
-                sizes["knn_tile_anchored"] = knn_tile_anchored._cache_size()
-            except AttributeError:                  # pragma: no cover
-                pass
+            from ..kernels.knn_tile import knn_tile, knn_tile_anchored
+            sizes["knn_tile"] = knn_tile._cache_size()
+            sizes["knn_tile_anchored"] = knn_tile_anchored._cache_size()
         return {
             **self._metrics.counters(),
             "last": dict(self._last),

@@ -177,12 +177,10 @@ def _update_impl(grid: CellGrid, points: Array, anchor_points: Array,
     spec = grid.spec
     valid = jnp.logical_not(parked_mask(points)) if mask_parked else None
     if use_pallas:
-        from ..kernels.ops import INTERPRET
         from ..kernels.update_tile import bin_disp_tile
         ccoord, oob, max_d2 = bin_disp_tile(points, anchor_points, spec,
                                             origin=origin,
-                                            mask_parked=mask_parked,
-                                            interpret=INTERPRET)
+                                            mask_parked=mask_parked)
     else:
         ccoord, oob, max_d2 = _bin_and_stats(spec, points, anchor_points,
                                              origin, valid)

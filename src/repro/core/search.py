@@ -144,10 +144,12 @@ def window_search(
 
 
 def _tile_d2(q: Array, cand_pos: Array) -> Array:
-    """[T, 3] x [T, M, 3] -> [T, M] squared distances (batched MXU form)."""
+    """[T, 3] x [T, M, 3] -> [T, M] squared distances (batched MXU form;
+    ``HIGHEST`` keeps f32 products on the TPU too)."""
     qn = jnp.sum(q * q, axis=-1, keepdims=True)              # [T, 1]
     pn = jnp.sum(cand_pos * cand_pos, axis=-1)               # [T, M]
-    cross = jnp.einsum("td,tmd->tm", q, cand_pos)
+    cross = jnp.einsum("td,tmd->tm", q, cand_pos,
+                       precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(qn + pn - 2.0 * cross, 0.0)
 
 

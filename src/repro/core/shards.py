@@ -56,15 +56,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax >= 0.5 promotes shard_map to jax.shard_map and renames the replication
-# check kwarg check_rep -> check_vma; this repo must run on both.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
 from .. import obs
 from . import api
 from .dynamic import SessionOpts, validate_session_opts
@@ -516,9 +507,9 @@ def make_sharded_query(mesh: Mesh, layout: SlabLayout,
     local_fn = _local_query_fn(layout, params, opts, slab_axis)
     q_spec = (P(slab_axis, query_axis) if query_axis is not None
               else P(slab_axis))
-    fn = _shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                     in_specs=(P(slab_axis), P(slab_axis), q_spec),
-                    out_specs=(q_spec, q_spec, q_spec), **_SHARD_MAP_KW)
+                    out_specs=(q_spec, q_spec, q_spec), check_vma=False)
 
     @jax.jit
     def run(pts, ids, queries):
@@ -801,19 +792,19 @@ class ShardedSession:
             raise RuntimeError("slab routing overflowed its own layout")
 
         ax = self._axis
-        init_fn = _shard_map(
+        init_fn = jax.shard_map(
             _local_init_fn(layout, self.params, self.opts, margin, ax),
             mesh=self._mesh, in_specs=(P(ax), P(ax)),
-            out_specs=(P(ax),) * 5, **_SHARD_MAP_KW)
+            out_specs=(P(ax),) * 5, check_vma=False)
         (self._pts, self._ids, self._index, self._plan,
          self._mig_total) = jax.jit(init_fn)(pts, ids)
 
         local = _local_step_fn(layout, self.params, self.opts, thr2,
                                margin, ax)
-        step_inner = _shard_map(
+        step_inner = jax.shard_map(
             local, mesh=self._mesh,
             in_specs=(P(ax), P(ax), P(ax), P(ax), P(ax), P()),
-            out_specs=(P(ax),) * 10, **_SHARD_MAP_KW)
+            out_specs=(P(ax),) * 10, check_vma=False)
         n = self._n
 
         def step_prog(pts, ids, index, plan, mig_total, pg):

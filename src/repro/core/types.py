@@ -159,7 +159,8 @@ class SearchOpts:
     schedule: bool = True              # section 4: Morton query ordering
     partition: bool = True             # section 5.1: megacell partitioning
     bundle: bool = True                # section 5.2: cost-model bundling
-    use_pallas: bool = False           # fused kernels (interpret on CPU)
+    use_pallas: bool = False           # fused kernels (Pallas interpreter
+    #                                    off-TPU; refused on a TPU backend)
     query_tile: int = 256              # queries per jnp/kernel tile
     w_max: int = 6                     # max megacell growth rings examined
     executor: bool = True              # device-resident QueryExecutor path

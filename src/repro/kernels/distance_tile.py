@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 DEFAULT_TQ = 256
 DEFAULT_TP = 512
 COORD_PAD = 8  # sublane-aligned coordinate dim (3 -> 8)
@@ -39,7 +41,7 @@ def distance_tile(
     *,
     tq: int = DEFAULT_TQ,
     tp: int = DEFAULT_TP,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Pairwise squared distances [Nq, Np] of q [Nq, 3] and p [Np, 3].
 
@@ -65,6 +67,6 @@ def distance_tile(
         out_specs=pl.BlockSpec((tq, tp), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], pt.shape[1]),
                                        jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, pt)
     return out[:nq, :npts]

@@ -51,9 +51,17 @@ before returning, so padded and unpadded results are bit-identical — the
 same code path runs in interpret mode on CPU CI. The query-tile sublane
 dimension TQ must be a multiple of 8 (asserted).
 
-Deployment notes: a points table larger than VMEM must be sharded or kept
-in ANY/HBM with manual DMA; on this container the kernels run in interpret
-mode.
+TPU status: Mosaic (the TPU Pallas compiler) refuses both in-kernel
+gathers — the row gather of candidate positions from the coordinate table
+(``jnp.take(pts, idx, axis=0)``: "Shape mismatch in input, indices and
+output") and the 1-D gather of candidate ids from the flattened dense grid
+in :func:`knn_tile_anchored` ("Only 2D gather is supported"). The fused
+path therefore runs only in the Pallas interpreter (CPU), and
+``build_index`` refuses ``SearchOpts(use_pallas=True)`` on a TPU backend
+(``kernels/ops.check_fused_path``). Making it compile needs a different
+data layout — cell-sorted points with the tables kept in HBM and each
+tile's window fetched by DMA (DESIGN.md section 2). The same rewrite lifts
+the VMEM-resident table limit on scene size.
 """
 from __future__ import annotations
 
@@ -63,6 +71,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import resolve_interpret
 
 DEFAULT_TQ = 256
 DEFAULT_TM = 512
@@ -91,10 +101,11 @@ def _merge_topk(best_d2, best_idx, d2, idx, k: int):
     def body(j, carry):
         md2, out_d2, out_idx = carry
         dmin = jnp.min(md2, axis=1, keepdims=True)        # [TQ, 1]
-        # first occurrence one-hot of the row min
-        is_min = md2 == dmin
-        first = jnp.cumsum(is_min.astype(jnp.int32), axis=1) == 1
-        oh = is_min & first
+        # first occurrence one-hot of the row min: the lowest lane holding
+        # it (a lane min; Mosaic has no cumsum lowering)
+        first = jnp.min(jnp.where(md2 == dmin, lane, width), axis=1,
+                        keepdims=True)
+        oh = lane == first
         imin = jnp.max(jnp.where(oh, midx, _NEG_I32), axis=1, keepdims=True)
         col = jax.lax.broadcasted_iota(jnp.int32, out_d2.shape, 1)
         out_d2 = jnp.where(col == j, dmin, out_d2)
@@ -157,7 +168,7 @@ def _knn_kernel(q_ref, pts_ref, idx_ref, out_d2_ref, out_idx_ref,
         best_d2[...] = jnp.full_like(best_d2, _BIG)
         best_idx[...] = jnp.full_like(best_idx, -1)
 
-    _stream_candidates(q_ref[...], pts_ref[...], idx_ref[0], best_d2,
+    _stream_candidates(q_ref[...], pts_ref[...], idx_ref[0, 0], best_d2,
                        best_idx, k=k, r2=r2, skip_test=skip_test,
                        n_pts=n_pts)
 
@@ -179,7 +190,7 @@ def knn_tile(
     skip_test: bool = False,
     tq: int = DEFAULT_TQ,
     tm: int = DEFAULT_TM,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Streaming top-K of each query against its tile's candidate id window.
 
@@ -192,7 +203,10 @@ def knn_tile(
     kp = _pad_lane(k)        # block minor dim; logical K sliced off below
     n_pts = points.shape[0]
     m_pad = (-m) % tm
-    wnd_idx = jnp.pad(wnd_idx, ((0, 0), (0, m_pad)), constant_values=-1)
+    # [n_tiles, 1, M]: the (1, tm) id block then spans the array's own
+    # unit sublane dimension (a (1, tm) block of [n_tiles, M] is refused)
+    wnd_idx = jnp.pad(wnd_idx, ((0, 0), (0, m_pad)),
+                      constant_values=-1)[:, None, :]
     # coordinate table: coords padded to the register width, rows padded to
     # the sublane multiple; pad rows park far away (never selected: gather
     # indices are clipped to n_pts-1 and -1 slots are masked)
@@ -201,7 +215,7 @@ def knn_tile(
                    ((0, n_row_pad), (0, COORD_PAD - 3)),
                    constant_values=0.0)
     qp = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, COORD_PAD - 3)))
-    n_m = wnd_idx.shape[1] // tm
+    n_m = wnd_idx.shape[2] // tm
 
     kernel = functools.partial(_knn_kernel, k=k, r2=float(r2),
                                skip_test=bool(skip_test), n_m=n_m,
@@ -214,7 +228,7 @@ def knn_tile(
             # full table, constant index map: stays VMEM-resident across
             # the candidate stream instead of re-fetching per step
             pl.BlockSpec((n_pts + n_row_pad, COORD_PAD), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, tm), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, tm), lambda i, j: (i, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((tq, kp), lambda i, j: (i, 0)),
@@ -228,7 +242,7 @@ def knn_tile(
             pltpu.VMEM((tq, kp), jnp.float32),
             pltpu.VMEM((tq, kp), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, pts8, wnd_idx)
     return out_d2[:, :k], out_idx[:, :k]
 
@@ -306,7 +320,7 @@ def knn_tile_anchored(
     skip_test: bool = False,
     tq: int = DEFAULT_TQ,
     tm: int = DEFAULT_TM,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """One masked launch of the level-segmented schedule: every query tile
     whose ``levels`` entry equals ``level`` streams its anchored
@@ -366,7 +380,7 @@ def knn_tile_anchored(
             jax.ShapeDtypeStruct((n_tiles * tq, kp), jnp.float32),
             jax.ShapeDtypeStruct((n_tiles * tq, kp), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(anchors.astype(jnp.int32), levels.astype(jnp.int32), qp, pts8,
       dense_flat)
     return out_d2[:, :k], out_idx[:, :k]

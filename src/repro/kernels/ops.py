@@ -2,9 +2,11 @@
 
 ``window_search_pallas`` matches ``core.search.window_search``'s signature
 so `SearchOpts(use_pallas=True)` swaps the jnp tile path for the fused
-kernel path. On this CPU container the kernels run in interpret mode
-(correctness); on TPU set ``interpret=False`` via `PALLAS_INTERPRET=0`
-(knob reference: DESIGN.md section 4).
+kernel path. The kernel mode follows the backend (``kernels.
+resolve_interpret``): the interpreter off-TPU, Mosaic on a TPU. Mosaic
+refuses the fused kNN kernel's in-kernel gathers, so on a TPU backend
+``build_index`` refuses the fused path up front (:func:`check_fused_path`)
+instead of interpreting it or taking the jnp path behind the caller's back.
 
 Tile-window semantics: each Morton-contiguous query tile gathers ONE shared
 cell window (the union of its members' windows) — that is the coherence
@@ -27,7 +29,6 @@ by scalar prefetch.
 """
 from __future__ import annotations
 
-import os
 from functools import lru_cache
 
 import jax
@@ -38,7 +39,23 @@ from .knn_tile import knn_tile, knn_tile_anchored
 from .range_tile import range_count
 from .update_tile import bin_disp_tile
 
-INTERPRET = os.environ.get("PALLAS_INTERPRET", "1") != "0"
+FUSED_PATH_REFUSAL = (
+    "SearchOpts(use_pallas=True) cannot run on a TPU backend: the fused kNN "
+    "kernel gathers inside the kernel (jnp.take of candidate rows from the "
+    "coordinate table, and of candidate ids from the flattened dense grid "
+    "in knn_tile_anchored), and the TPU Pallas compiler (Mosaic) refuses "
+    "both gathers. Use the default jnp path (use_pallas=False); the fused "
+    "path needs a cell-sorted layout with DMA window fetches first "
+    "(DESIGN.md section 2).")
+
+
+def check_fused_path() -> None:
+    """Refuse the fused search path where it cannot compile (a TPU
+    backend), naming the refused in-kernel gathers. Called by
+    ``build_index`` for ``SearchOpts(use_pallas=True)``; off-TPU the
+    kernels run in the Pallas interpreter and the path is allowed."""
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(FUSED_PATH_REFUSAL)
 
 
 @lru_cache(maxsize=512)
@@ -148,8 +165,6 @@ def window_search_segmented(
     itself works purely in cell space, so only the anchor computation here
     needs the frame.
     """
-    if interpret is None:
-        interpret = INTERPRET
     nq = queries.shape[0]
     n_tiles = nq // tile
     assert n_tiles * tile == nq, (nq, tile)
@@ -223,4 +238,4 @@ def window_search_pallas(
 __all__ = ["bin_disp_tile", "distance_tile", "knn_tile",
            "knn_tile_anchored", "range_count", "segment_levels",
            "assign_tile_levels", "window_search_segmented",
-           "window_search_pallas", "INTERPRET"]
+           "window_search_pallas", "check_fused_path"]

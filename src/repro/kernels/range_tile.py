@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 DEFAULT_TQ = 256
 DEFAULT_TM = 512
 COORD_PAD = 8
@@ -51,7 +53,7 @@ def range_count(
     r2: float,
     tq: int = DEFAULT_TQ,
     tm: int = DEFAULT_TM,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Per-query count of window candidates within sqrt(r2). Returns [Nq]."""
     assert tm % LANES == 0
@@ -75,6 +77,6 @@ def range_count(
         ],
         out_specs=pl.BlockSpec((tq, LANES), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * tq, LANES), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, wnd_pos_t, wnd_idx)
     return jnp.sum(out, axis=1)

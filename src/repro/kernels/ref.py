@@ -20,11 +20,15 @@ def pairwise_d2(q: Array, p: Array) -> Array:
     Expanded form |q|^2 + |p|^2 - 2 q.p^T: the -2 q.p^T term is a matmul —
     on TPU this is the MXU formulation the distance kernel uses (DESIGN.md
     section 2, Step 2); the oracle uses the same math so tolerance behaviour
-    matches.
+    matches. The cross term runs at ``Precision.HIGHEST``: at the default
+    precision the TPU multiplies f32 operands in one bf16 pass, an error
+    (~4e-3 on unit-scale coordinates) far above a typical r^2. The CPU
+    computes f32 products either way.
     """
     qn = jnp.sum(q * q, axis=-1, keepdims=True)            # [Nq, 1]
     pn = jnp.sum(p * p, axis=-1, keepdims=True).T          # [1, Np]
-    cross = q @ p.T                                         # [Nq, Np] (MXU)
+    cross = jnp.matmul(q, p.T,                              # [Nq, Np] (MXU)
+                       precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(qn + pn - 2.0 * cross, 0.0)
 
 

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import resolve_interpret
+
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
                  out_ref, sT_ref, state, *, seq: int):
@@ -46,7 +48,7 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def rwkv_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
               u: jax.Array, state0: jax.Array, *,
-              interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+              interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
     """r/k/v/w [B, S, H, hd] f32, u [H, hd], state0 [B, H, hd, hd] f32
     -> (out [B, S, H, hd], state_T [B, H, hd, hd])."""
     b, s, h, hd = r.shape
@@ -76,7 +78,7 @@ def rwkv_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((b * h, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rf, kf, vf, wf, uf, s0)
     out = out.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
     return out, s_t.reshape(b, h, hd, hd)

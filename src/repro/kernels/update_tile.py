@@ -12,7 +12,11 @@ from VMEM once and reduced in-register.
 
 Grid: (N / TN,). Coordinates are padded 3 -> 8 sublanes like the other
 kernels in this package (zero columns change no statistic: they are masked
-out of the bounds test and contribute 0 to displacement).
+out of the bounds test and contribute 0 to displacement). Each tile writes
+its two partial statistics broadcast across one ``(1, 128)`` lane row of a
+``[n_tiles, 1, 128]`` output: a block whose last two dimensions equal the
+array's, which Mosaic accepts (a ``(1, 1)`` block of ``[n_tiles, 1]`` is
+refused for its sublane dimension).
 """
 from __future__ import annotations
 
@@ -22,8 +26,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 DEFAULT_TN = 256
 COORD_PAD = 8
+LANE = 128
 
 
 @functools.partial(jax.jit,
@@ -37,7 +44,7 @@ def bin_disp_tile(
     origin: jax.Array | None = None,
     mask_parked: bool = False,
     tn: int = DEFAULT_TN,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused binning + stats of ``points`` [N, 3] against ``anchor_points``.
 
@@ -91,12 +98,13 @@ def bin_disp_tile(
         c = jnp.floor((p - o) * inv_cell).astype(jnp.int32)
         escaped = ((c < 0) | (c > h)) & real_col
         oob_row = jnp.any(escaped, axis=1, keepdims=True)   # [TN, 1]
-        oob_ref[0, 0] = jnp.sum(
-            (oob_row & real_row).astype(jnp.int32))
+        oob = jnp.sum((oob_row & real_row).astype(jnp.int32))
+        oob_ref[...] = jnp.full(oob_ref.shape, oob, jnp.int32)
 
         d = p - a                                           # pad cols: 0
         d2 = jnp.sum(d * d, axis=1, keepdims=True)          # [TN, 1]
-        d2_ref[0, 0] = jnp.max(jnp.where(real_row, d2, 0.0))
+        d2max = jnp.max(jnp.where(real_row, d2, 0.0))
+        d2_ref[...] = jnp.full(d2_ref.shape, d2max, jnp.float32)
 
         cc_ref[...] = jnp.clip(c, 0, h)
 
@@ -111,14 +119,15 @@ def bin_disp_tile(
         ],
         out_specs=[
             pl.BlockSpec((tn, COORD_PAD), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, LANE), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, LANE), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((pp.shape[0], COORD_PAD), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, 1, LANE), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, LANE), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pp, ap, origin, hi)
-    return cc[:n, :3], jnp.sum(oob_part), jnp.max(d2_part)
+    return (cc[:n, :3], jnp.sum(oob_part[:, 0, 0]),
+            jnp.max(d2_part[:, 0, 0]))

@@ -29,21 +29,6 @@ def make_production_mesh(*, multi_pod: bool = False):
     return Mesh(dev_array, axes)
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across JAX versions: newer JAX wants explicit
-    ``axis_types`` on meshes fed to shard_map, older JAX has no such kwarg
-    (and no ``jax.sharding.AxisType``). Feature-detect, don't version-sniff.
-    """
-    import jax
-
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
-
-
 def make_slab_mesh(n_slabs: int | None = None, axis: str = "data"):
     """1-D slab mesh for the sharded-scene subsystem (``core/shards.py``).
 
@@ -53,6 +38,7 @@ def make_slab_mesh(n_slabs: int | None = None, axis: str = "data"):
     host devices — see scripts/ci.sh).
     """
     import jax
+    from jax.sharding import AxisType
 
     devs = jax.devices()
     n = int(n_slabs) if n_slabs else len(devs)
@@ -61,16 +47,8 @@ def make_slab_mesh(n_slabs: int | None = None, axis: str = "data"):
             f"need {n} devices for a {n}-slab mesh, have {len(devs)} — "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{n}")
-    if n == len(devs):
-        return make_mesh_compat((n,), (axis,))
-    from jax.sharding import Mesh
-
-    dev_array = np.asarray(devs[:n]).reshape(n)
-    try:
-        from jax.sharding import AxisType
-        return Mesh(dev_array, (axis,), axis_types=(AxisType.Auto,))
-    except (ImportError, TypeError):
-        return Mesh(dev_array, (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,),
+                         devices=devs[:n])
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):

@@ -15,13 +15,18 @@ The trace is deterministic per ``--seed`` (arrival process included), so
 two runs drain identical batch sequences — the property the serve tests
 pin down.
 
+Every submitted request is accounted to exactly one terminal outcome
+({result, DeadlineExceeded, QueryError, Rejected, CircuitOpen, ...}).
+Without a fault plan every request must come back as a result: any other
+outcome (a batch that failed to compile, an expired deadline, a
+rejection) exits nonzero.
+
 **Chaos mode** (DESIGN.md section 11): with ``REPRO_FAULTS`` set (e.g.
 ``REPRO_FAULTS=launch:0.2,straggler:0.1``) the same trace runs under
 seeded fault injection. The driver then acts as the reliability gate: it
-accounts every submitted request to exactly one terminal outcome
-({result, DeadlineExceeded, QueryError, Rejected, CircuitOpen, ...}),
 prints the outcome and injected-fault tables, and exits nonzero if ANY
-future hangs (fails to resolve within the timeout) or goes unaccounted.
+future hangs (fails to resolve within the timeout) or goes unaccounted;
+the injected failures themselves are expected outcomes.
 
   REPRO_FAULTS=launch:0.2,straggler:0.1 \\
       PYTHONPATH=src python -m repro.launch.serve --trace short
@@ -120,6 +125,8 @@ def main(argv=None):
         args.scenes, args.points = min(args.scenes, 2), 1200
         args.requests, args.qmax = 64, 32
 
+    from repro.launch.cache import configure_compile_cache
+    configure_compile_cache()
     from repro import obs
     from repro.obs import flight, slo
     from repro.reliability import faults
@@ -236,10 +243,15 @@ def main(argv=None):
         if dumped:
             print(f"serve: flight recorder dumped to {dumped}",
                   file=sys.stderr)
-    fail = hung or accounted != len(trace)
+    # with no fault plan armed, nothing but a result is acceptable: a
+    # failed or refused request is a broken serving path, not chaos
+    not_served = ({} if plan is not None else
+                  {k: v for k, v in outcomes.items() if k != "result"})
+    fail = hung or accounted != len(trace) or not_served
     if fail:
         print(f"serve: FAILED — hung futures: {hung}, accounted "
-              f"{accounted}/{len(trace)}", file=sys.stderr)
+              f"{accounted}/{len(trace)}, not served without a fault "
+              f"plan: {not_served or 'none'}", file=sys.stderr)
     viol = slo.violations()
     for tenant, (att, obj) in sorted(viol.items()):
         print(f"serve: SLO VIOLATION — tenant {tenant} attainment "

@@ -106,10 +106,7 @@ class SceneVariant:
     def compiled_programs(self) -> int:
         """Entries in the variant-private jit cache (tests assert re-warm
         after eviction/readmission through this)."""
-        try:
-            return int(self.fn._cache_size())
-        except AttributeError:          # pragma: no cover - older jax
-            return len(self.warmed)
+        return int(self.fn._cache_size())
 
     def release(self) -> None:
         """Drop compiled state: executor plan/compile caches and the

@@ -41,10 +41,15 @@ except ImportError:
         seq = list(seq)
         return _Strategy(lambda rng: seq[int(rng.integers(0, len(seq)))])
 
-    def _lists(elem, min_size=0, max_size=10, **_kw):
+    def _lists(elem, min_size=0, max_size=10, unique=False, **_kw):
         def draw(rng):
             n = int(rng.integers(min_size, max_size + 1))
-            return [elem.draw(rng) for _ in range(n)]
+            out = []
+            while len(out) < n:
+                x = elem.draw(rng)
+                if not (unique and x in out):
+                    out.append(x)
+            return out
         return _Strategy(draw)
 
     def _tuples(*elems):

@@ -1,5 +1,7 @@
 """Multi-device tests (subprocess: jax device count is locked at first
-init, so each test spawns a fresh interpreter with 8 host devices)."""
+init, so each test spawns a fresh interpreter with 8 host devices). The
+children are CPU-only by design: JAX_PLATFORMS=cpu keeps them off any
+accelerator, which belongs to one process at a time."""
 import os
 import subprocess
 import sys
@@ -7,6 +9,7 @@ import sys
 import pytest
 
 ENV = {**os.environ, "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+       "JAX_PLATFORMS": "cpu",
        "PYTHONPATH": os.path.join(os.path.dirname(__file__), "..", "src")}
 
 
@@ -23,12 +26,13 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.core.distributed import distributed_neighbor_search
 from repro.core.types import SearchParams
 from repro.kernels.ref import brute_force_search
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 rng = np.random.default_rng(3)
 pts = rng.random((4000, 3)).astype(np.float32)
 qs = rng.random((900, 3)).astype(np.float32)
 r, K = 0.07, 8
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 res = distributed_neighbor_search(mesh, pts, qs, SearchParams(radius=r, k=K))
 oi, od, oc = brute_force_search(jnp.asarray(pts), jnp.asarray(qs), r, K)
 assert np.array_equal(np.asarray(oi), np.asarray(res.indices))
@@ -229,10 +233,11 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.core.distributed import distributed_neighbor_search
 from repro.core.types import SearchParams
 from repro.kernels.ref import brute_force_search
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 
 def check(pts, qs, r=0.08, K=8):
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     res = distributed_neighbor_search(mesh, pts, qs,
                                       SearchParams(radius=r, k=K))
     oi, od, oc = brute_force_search(jnp.asarray(pts), jnp.asarray(qs),
@@ -287,11 +292,9 @@ def test_api_query_composes_with_shard_map():
     per-scene results must match the single-device call bitwise."""
     out = _run("""
 import numpy as np, jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 import repro.api as api
 from repro.core import SearchOpts, SearchParams, choose_grid_spec
-from repro.core.distributed import _shard_map, _SHARD_MAP_KW
-from repro.launch.mesh import make_mesh_compat
 rng = np.random.default_rng(5)
 B = 8
 params = SearchParams(radius=0.1, k=8, knn_window="exact")
@@ -301,10 +304,10 @@ spec = choose_grid_spec(np.concatenate(scenes), params.radius)
 idxs = [api.build_index(s, params, SearchOpts(), spec=spec) for s in scenes]
 stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *idxs)
 qstack = jnp.stack([jnp.asarray(q) for q in qss])
-mesh = make_mesh_compat((B,), ("pod",))
-fn = _shard_map(lambda idx, qs: jax.vmap(api.query)(idx, qs),
-                mesh=mesh, in_specs=(P("pod"), P("pod")),
-                out_specs=P("pod"), **_SHARD_MAP_KW)
+mesh = jax.make_mesh((B,), ("pod",), axis_types=(AxisType.Auto,))
+fn = jax.shard_map(lambda idx, qs: jax.vmap(api.query)(idx, qs),
+                   mesh=mesh, in_specs=(P("pod"), P("pod")),
+                   out_specs=P("pod"), check_vma=False)
 res = jax.jit(fn)(stacked, qstack)
 for b in range(B):
     one = api.query(idxs[b], qss[b])

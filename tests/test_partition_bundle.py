@@ -58,13 +58,7 @@ def _mk_parts(ns, ws):
     return out
 
 
-@given(st.lists(st.integers(1, 10000), min_size=1, max_size=6),
-       st.lists(st.integers(1, 8), min_size=1, max_size=6))
-@settings(deadline=None, max_examples=40)
-def test_bundling_matches_exhaustive_under_inverse_correlation(ns, ws):
-    """Appendix C theorem: the linear-scan suffix-merge strategy achieves
-    the exhaustive optimum when N and S are inversely correlated."""
-    parts = _mk_parts(ns, ws)
+def _assert_planned_is_optimal(parts):
     model = CostModel()
     kw = dict(n_points=50_000, cell_size=0.1, mode="knn", k=8, w_sph=10)
     planned = plan_bundles(parts, model, **kw)
@@ -72,6 +66,25 @@ def test_bundling_matches_exhaustive_under_inverse_correlation(ns, ws):
     got_cost = total_cost(planned, parts, model,
                           n_points=50_000, cell_size=0.1, mode="knn", k=8)
     assert got_cost <= best_cost * (1 + 1e-9), (got_cost, best_cost)
+
+
+@given(st.lists(st.integers(1, 10000), min_size=1, max_size=6, unique=True),
+       st.lists(st.integers(1, 8), min_size=1, max_size=6))
+@settings(deadline=None, max_examples=40)
+def test_bundling_matches_exhaustive_under_inverse_correlation(ns, ws):
+    """Appendix C theorem: the linear-scan suffix-merge strategy achieves
+    the exhaustive optimum when N and S are inversely correlated. Query
+    counts are distinct: with four equal counts (no correlation at all)
+    the optimum pairs the partitions into two merged bundles, a shape the
+    one-merged-bundle strategy cannot take."""
+    _assert_planned_is_optimal(_mk_parts(ns, ws))
+
+
+def test_bundling_equal_counts_merge_widest_first():
+    """Equal query counts: the widest windows are merged first (three
+    single-query partitions at windows 7, 8, 9 — merging 8 and 9 is the
+    optimum; merging 7 and 8 is not)."""
+    _assert_planned_is_optimal(_mk_parts([1, 1, 1], [7]))
 
 
 def test_bundling_disabled_is_listing3(rng):
