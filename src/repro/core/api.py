@@ -308,14 +308,18 @@ def _execute_plan_scoped(index, queries, plan):
             grid, points, qs, spec, plan.ladder, plan.tile_levels,
             params.radius, k, tile, origin=index.origin)
     else:
-        def _branch(w, skip):
+        def _branch(e, w, skip):
             def run(qt):
-                return window_tile_search(grid, points, qt, spec, w,
-                                          params.radius, k, skip,
-                                          origin=index.origin)
+                # the Pallas path's per-level scope names (kernels/ops), so
+                # a device trace gives each ladder level its own time
+                with jax.named_scope(f"repro.launch.level{e}_w{w}"):
+                    return window_tile_search(grid, points, qt, spec, w,
+                                              params.radius, k, skip,
+                                              origin=index.origin)
             return run
 
-        branches = [_branch(w, s) for (w, s) in plan.ladder]
+        branches = [_branch(e, w, s)
+                    for e, (w, s) in enumerate(plan.ladder)]
 
         def one_tile(args):
             qt, lvl = args

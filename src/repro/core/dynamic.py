@@ -42,7 +42,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 
 import jax
 import jax.numpy as jnp
@@ -146,6 +145,7 @@ class StepReport:
     max_disp: float = 0.0      # from the packed telemetry vector
     overflow: int = 0
     oob: int = 0
+    compiles: int = 0          # programs JAX compiled during the step
 
 
 def validate_session_opts(sopts: SessionOpts) -> None:
@@ -324,7 +324,7 @@ class SimulationSession:
 
     def stats(self) -> dict:
         counters = dict(steps=0, fast_steps=0, replans=0, respecs=0,
-                        stats_fetches=0, host_syncs=0)
+                        stats_fetches=0, host_syncs=0, compiles=0)
         counters.update(self._metrics.counters())
         return {
             **counters,
@@ -347,15 +347,11 @@ class SimulationSession:
 
     def _dispatch_synced(self, index, pts, q, anchor_q, force, self_query):
         """Launch the fused step, then fetch the packed telemetry vector —
-        still the session's ONE blocking transfer per step. A jit compile
-        is detected from step-cache growth and recorded as a compile span
-        nested under the launch."""
-        cache0 = int(self._step_fn._cache_size())
+        still the session's ONE blocking transfer per step. A compile of
+        the step program shows as a ``compile`` span nested under the
+        launch (``obs/compiles.py``)."""
         with obs.span("launch", forced=bool(force)):
-            t0 = time.perf_counter()
             out = self._dispatch(index, pts, q, anchor_q, force, self_query)
-            if int(self._step_fn._cache_size()) > cache0:
-                obs.record_span("compile", time.perf_counter() - t0)
         with obs.span("sync"):
             telem = obs.unpack_step_telemetry(
                 np.asarray(jax.device_get(out[4])))
@@ -373,6 +369,7 @@ class SimulationSession:
         """
         rep = StepReport()
         m = self._metrics
+        compiles0 = obs.thread_compiles()
         with obs.span("step") as sp_step:
             pts = jnp.asarray(points, jnp.float32)
             self_query = queries is None or queries is points
@@ -469,6 +466,9 @@ class SimulationSession:
                 m.count(f"level_occ_{lvl}", occ)
             m.gauge("staleness_disp2", tel["max_disp2"])
             m.gauge("step_cache_size", int(self._step_fn._cache_size()))
+            rep.compiles = obs.thread_compiles() - compiles0
+            m.count("compiles", rep.compiles)
+            sp_step.set(compiles=rep.compiles)
         rep.t_search = sp_step.duration
         m.observe("step_s", rep.t_search)
         self.report = rep

@@ -172,8 +172,11 @@ class QueryExecutor:
         if partitioned:
             w_dev, s_dev, r_dev = compute_megacells(
                 ns.grid, queries_s, ns.statics, ns.params)
-            w_np, s_np, r_np = (np.asarray(a) for a in jax.device_get(
-                (w_dev, s_dev, r_dev)))
+            # a blocking transfer: its own span, so host planning is not
+            # blamed for the wait
+            with obs.span("sync"):
+                w_np, s_np, r_np = (np.asarray(a) for a in jax.device_get(
+                    (w_dev, s_dev, r_dev)))
             self._last["plan_fetches"] += 1
             if margin:
                 w_np, s_np = inflate_plan_inputs(
@@ -301,19 +304,22 @@ class QueryExecutor:
         @partial(jax.jit, donate_argnums=(5, 6, 7))
         def launcher(grid, points, queries_s, perm, sels,
                      out_idx, out_d2, out_cnt):
-            for (w, skip, _pad_n), sel in zip(metas, sels):
-                # sel arrives edge-padded to the bucket: padded slots repeat
-                # the group's last real query, so their searched rows are
-                # identical to that query's row and the duplicate scatter
-                # writes below are idempotent
-                qb = queries_s[sel]
-                idx, d2, cnt = searcher(grid, points, qb, spec, w, radius,
-                                        k, skip, tile)
-                orig = perm[sel]
-                out_idx = out_idx.at[orig].set(idx)
-                out_d2 = out_d2.at[orig].set(d2)
-                out_cnt = out_cnt.at[orig].set(cnt)
-            return out_idx, out_d2, out_cnt
+            # the scope of api.execute_plan: the search metrics read the
+            # executor's launches by the same name
+            with jax.named_scope("repro.execute_plan"):
+                for (w, skip, _pad_n), sel in zip(metas, sels):
+                    # sel arrives edge-padded to the bucket: padded slots
+                    # repeat the group's last real query, so their searched
+                    # rows are identical to that query's row and the
+                    # duplicate scatter writes below are idempotent
+                    qb = queries_s[sel]
+                    idx, d2, cnt = searcher(grid, points, qb, spec, w,
+                                            radius, k, skip, tile)
+                    orig = perm[sel]
+                    out_idx = out_idx.at[orig].set(idx)
+                    out_d2 = out_d2.at[orig].set(d2)
+                    out_cnt = out_cnt.at[orig].set(cnt)
+                return out_idx, out_d2, out_cnt
 
         self._launcher_cache[key] = launcher
         if len(self._launcher_cache) > _LAUNCHER_CACHE_MAX:
@@ -343,10 +349,12 @@ class QueryExecutor:
         rides the pending record, not executor scratch state.
         """
         ns = self.ns
+        # compiles: programs JAX compiled while planning and dispatching
         last = dict(host_syncs=0, plan_fetches=0, launches=0,
-                    dispatches=0, compilations=0, bundles=0,
+                    dispatches=0, compilations=0, compiles=0, bundles=0,
                     plan_cache_hit=False, plan_reused=False,
                     launcher_cache_hit=False)
+        compiles0 = obs.thread_compiles()
         self._last = last
         queries = jnp.asarray(queries, jnp.float32)
         nq = queries.shape[0]
@@ -361,8 +369,10 @@ class QueryExecutor:
             # fault-injection seam (reliability.faults): a scheduled
             # launch fault fails the dispatch before any device work
             faults.maybe_fail("launch")
-            return self._dispatch_pending(queries, nq, k, reuse, last,
-                                          sp_query)
+            pending = self._dispatch_pending(queries, nq, k, reuse, last,
+                                             sp_query)
+            last["compiles"] = obs.thread_compiles() - compiles0
+            return pending
         except BaseException:
             sp_query.__exit__(None, None, None)
             raise
@@ -398,15 +408,11 @@ class QueryExecutor:
             # launcher only ever sees bucketed shapes (zero retraces on
             # count drift); the freshly-initialized output buffers are
             # donated into the program
-            t_disp = time.perf_counter()
             out_idx, out_d2, out_cnt = launcher(
                 ns.grid, ns.points, queries_s, perm, sels_dev,
                 jnp.full((nq, k), -1, jnp.int32),
                 jnp.full((nq, k), jnp.inf, jnp.float32),
                 jnp.zeros((nq,), jnp.int32))
-            if last["compilations"]:
-                # the jit compile happened inside that first dispatch
-                obs.record_span("compile", time.perf_counter() - t_disp)
         last["dispatches"] = 1
         return PendingResult(self, (out_idx, out_d2, out_cnt), last,
                              sp_query, t0)

@@ -73,18 +73,31 @@ def window_tile_search(
     dims = jnp.asarray(spec.dims, jnp.int32)
     ws_arr = jnp.asarray(ws, jnp.int32)
 
-    ccoord = spec.cell_of(qt, origin)                    # [T, 3]
-    start = jnp.clip(ccoord - w, 0, dims - ws_arr)       # [T, 3]
+    # each stage runs under a scope of its own, so a device trace of any
+    # path that runs this tile (api.execute_plan, the executor's launcher,
+    # the host loop) attributes its time by the same four names
+    with jax.named_scope("repro.search.window_gather"):
+        ccoord = spec.cell_of(qt, origin)                # [T, 3]
+        start = jnp.clip(ccoord - w, 0, dims - ws_arr)   # [T, 3]
 
-    def gather_one(st):
-        blk = jax.lax.dynamic_slice(
-            grid.dense, (st[0], st[1], st[2], 0),
-            (*ws, cap))
-        return blk.reshape(-1)
+        def gather_one(st):
+            blk = jax.lax.dynamic_slice(
+                grid.dense, (st[0], st[1], st[2], 0),
+                (*ws, cap))
+            return blk.reshape(-1)
 
-    cand = jax.vmap(gather_one)(start)                   # [T, W^3*C]
-    cand_pos = points[jnp.clip(cand, 0, points.shape[0] - 1)]
-    d2 = _tile_d2(qt, cand_pos)                          # [T, W^3*C]
+        cand = jax.vmap(gather_one)(start)               # [T, W^3*C]
+    with jax.named_scope("repro.search.row_gather"):
+        cand_pos = points[jnp.clip(cand, 0, points.shape[0] - 1)]
+    with jax.named_scope("repro.search.distance"):
+        d2 = _tile_d2(qt, cand_pos)                      # [T, W^3*C]
+    with jax.named_scope("repro.search.select"):
+        return _select(d2, cand, k, r2, skip_test)
+
+
+def _select(d2: Array, cand: Array, k: int, r2, skip_test: bool):
+    """Mask the out-of-range and empty candidates, then keep the ``k``
+    nearest: ([T, k] d2, [T, k] idx, [T] cnt)."""
     invalid = cand < 0
     if not skip_test:
         invalid = invalid | (d2 > r2)
@@ -168,9 +181,7 @@ class SearchReport:
     """Execution breakdown mirroring paper Fig. 12 categories, plus the
     executor's dispatch/sync counters (DESIGN.md section 3)."""
 
-    t_build: float = 0.0       # BVH   (grid build)
     t_opt: float = 0.0         # Opt   (schedule + partition + bundle planning)
-    t_fs: float = 0.0          # FS    (first-hit pass; closed-form here)
     t_search: float = 0.0      # Search
     bundles: list = dataclasses.field(default_factory=list)
     num_partitions: int = 0

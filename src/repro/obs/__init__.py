@@ -25,6 +25,10 @@ from .lifecycle import on_reset, run_reset_hooks  # noqa: F401
 from .perfetto import export_perfetto, to_trace_events  # noqa: F401
 from .openmetrics import export_openmetrics  # noqa: F401
 from . import slo, flight  # noqa: F401  (registers their reset hooks)
+from . import compiles as _compiles
+from .compiles import thread_compiles  # noqa: F401
+
+_compiles.install()
 
 
 def metric_set(component: str) -> MetricSet:

@@ -5,8 +5,10 @@ Converts the host span ring into the Trace Event JSON format that
 serve run can be inspected on the same timeline as a ``jax.profiler``
 capture. Each span becomes one complete event (``"ph": "X"``) with:
 
-* ``ts``/``dur`` in microseconds on the span's ``perf_counter`` clock
-  (``t0_s`` — relative placement is exact, absolute epoch is not);
+* ``ts``/``dur`` in microseconds; ``ts`` is the span's ``t0_s``, epoch
+  time on CLOCK_REALTIME, the clock of the profiler's TraceMe events, so
+  the spans line up with a ``jax.profiler`` capture of the same run once
+  its ``profile_start_time`` is subtracted;
 * ``tid`` = the recording thread (so the submit thread, pump thread and
   caller threads land on separate tracks);
 * ``args`` = the span's path, trace id (request-scoped spans) or

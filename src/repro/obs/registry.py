@@ -212,17 +212,21 @@ class Registry:
     def __init__(self):
         self._lock = threading.Lock()
         self._live: collections.OrderedDict = collections.OrderedDict()
+        self._kept: dict = {}               # never evicted (keep=True)
         self._retired: dict = {}            # component -> merged snapshot
         self._seq = 0
 
     # -- membership ---------------------------------------------------------
 
-    def metric_set(self, component: str) -> MetricSet:
-        """Create and register a new instance-scoped MetricSet."""
+    def metric_set(self, component: str, *, keep: bool = False) -> MetricSet:
+        """Create and register a new instance-scoped MetricSet. ``keep``
+        exempts it from eviction, for a process-wide set that counts for
+        the whole life of the process (``obs/compiles.py``): an evicted
+        set is folded in once and its later counts would be lost."""
         ms = MetricSet(component)
         with self._lock:
             self._seq += 1
-            self._live[self._seq] = ms
+            (self._kept if keep else self._live)[self._seq] = ms
             while len(self._live) > _LIVE_SETS_MAX:
                 _k, old = self._live.popitem(last=False)
                 _merge(self._retired.setdefault(old.component, {}),
@@ -232,6 +236,7 @@ class Registry:
     def reset(self) -> None:
         with self._lock:
             self._live.clear()
+            self._kept.clear()
             self._retired.clear()
 
     # -- reading ------------------------------------------------------------
@@ -242,7 +247,7 @@ class Registry:
         with self._lock:
             for comp, snap in self._retired.items():
                 _merge(out.setdefault(comp, {}), snap)
-            for ms in self._live.values():
+            for ms in (*self._kept.values(), *self._live.values()):
                 _merge(out.setdefault(ms.component, {}), ms.snapshot())
         return out
 

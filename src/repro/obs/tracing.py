@@ -15,14 +15,19 @@ profiles. Host-side *recording* is gated by the ``REPRO_TRACE`` env knob
 
 Span taxonomy (fixed, so dashboards and tests can rely on the names):
 top-level ``query`` (executor) and ``step`` (sessions); children ``plan``,
-``compile``, ``launch``, ``sync``. The serving stack (DESIGN.md section
-12) adds the request lifecycle: ``admit``/``admit/enqueue`` on the
-submit path and ``drain``/``stage``/``launch``/``sync``/``split``/
-``resolve`` on the drain path. Nesting is tracked per-thread; a span
+``compile``, ``launch``, ``sync``; the executor's blocking plan fetch is a
+``sync`` inside ``plan`` (``query/plan/sync``). The serving stack
+(DESIGN.md section 12) adds the request lifecycle:
+``admit``/``admit/enqueue`` on the submit path and
+``drain``/``stage``/``launch``/``sync``/``split``/``resolve`` on the
+drain path. Nesting is tracked per-thread; a span
 record carries its slash-joined path (``step/launch/compile``), its
-start time ``t0_s`` (``time.perf_counter`` clock — the clock the
-Perfetto exporter converts to microseconds), and the recording thread's
-``tid``.
+start time ``t0_s`` in seconds since the epoch on CLOCK_REALTIME
+(``time.time_ns``), the clock the profiler's TraceMe events use, so
+spans lie over a ``jax.profiler`` capture once the capture's
+``profile_start_time`` is subtracted; its duration ``dur_s``
+(``time.perf_counter``); and the recording thread's ``tid``. The
+``compile`` span comes from JAX's own compile events (``obs/compiles.py``).
 
 **Trace context** (section 12): ``with trace_scope("req-000042"): ...``
 pins a per-thread request id; every span recorded inside the scope (or
@@ -183,22 +188,27 @@ def _emit(rec: dict) -> None:
         logger.debug("span %s %.1fus", rec["path"], rec["dur_s"] * 1e6)
 
 
+def _now_s() -> float:
+    """Seconds since the epoch on CLOCK_REALTIME, the profiler's clock."""
+    return time.time_ns() * 1e-9
+
+
 def record_span(name: str, dur_s: float, *, t0_s: float | None = None,
                 **attrs) -> None:
-    """Record a span retroactively (for stages detected after the fact,
-    e.g. a compile identified from a jit cache-size delta after the launch
-    call returned). Nested under the current thread's open span, if any.
-    ``t0_s`` is the start on the ``perf_counter`` clock (defaults to
-    now-minus-duration); a ``trace=...`` attribute (or an enclosing
-    ``trace_scope``) is hoisted to the record's top-level ``trace``."""
+    """Record a span retroactively (for stages known only when they end,
+    e.g. a compile reported by JAX's monitoring events, or a request's
+    end-to-end ``resolve``). Nested under the current thread's open span,
+    if any. ``t0_s`` is the start in epoch seconds on the profiler's clock
+    (defaults to now-minus-duration); a ``trace=...`` attribute (or an
+    enclosing ``trace_scope``) is hoisted to the record's top-level
+    ``trace``."""
     if _mode == "off":
         return
     st = _stack()
     path = "/".join(st + [name])
     trace = attrs.pop("trace", None) or current_trace()
     rec = {"type": "span", "name": name, "path": path, "dur_s": dur_s,
-           "t0_s": (time.perf_counter() - dur_s if t0_s is None
-                    else float(t0_s)),
+           "t0_s": _now_s() - dur_s if t0_s is None else float(t0_s),
            "tid": threading.get_ident()}
     if trace is not None:
         rec["trace"] = trace
@@ -212,13 +222,15 @@ class span:
     it in the XLA profile, records it per REPRO_TRACE. ``sp.duration`` is
     available after exit; ``sp.set(**attrs)`` adds attributes mid-flight."""
 
-    __slots__ = ("name", "attrs", "duration", "_t0", "_ann", "_path")
+    __slots__ = ("name", "attrs", "duration", "_t0", "_t0_s", "_ann",
+                 "_path")
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
         self.duration = 0.0
         self._t0 = 0.0
+        self._t0_s = 0.0
         self._ann = None
         self._path = name
 
@@ -234,6 +246,7 @@ class span:
         # profiler is active
         self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
+        self._t0_s = _now_s()
         self._t0 = time.perf_counter()
         return self
 
@@ -246,7 +259,7 @@ class span:
         if _mode != "off":
             trace = self.attrs.pop("trace", None) or current_trace()
             rec = {"type": "span", "name": self.name, "path": self._path,
-                   "dur_s": self.duration, "t0_s": self._t0,
+                   "dur_s": self.duration, "t0_s": self._t0_s,
                    "tid": threading.get_ident()}
             if trace is not None:
                 rec["trace"] = trace

@@ -553,8 +553,6 @@ class NeighborService:
         compiled = variant.compiled_programs() > cache0
         if compiled:
             variant.warmed.add(staged.pad_n)
-            obs.record_span("compile", time.perf_counter() - t0,
-                            trace_ids=tids)
         return _InFlight(key, staged, result, t0, compiled, attempt)
 
     def _run_batch(self, key, requests, now: float) -> _InFlight | None:

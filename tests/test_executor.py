@@ -266,3 +266,35 @@ def test_warmup_yields_zero_compile_misses(rng):
     assert st["launcher_cache_misses"] == before
     assert st["last"]["compilations"] == 0
     assert st["last"]["plan_cache_hit"]
+
+
+def test_launcher_program_carries_the_search_scopes(rng):
+    """The executor's one launch program runs under the scope of
+    ``api.execute_plan``, with the per-tile search's stage scopes inside,
+    so the device-trace metrics read it by the same names; the plan
+    fetch is a ``sync`` of its own inside ``plan``."""
+    import re
+
+    from repro import obs
+    pts = rng.random((1200, 3)).astype(np.float32)
+    qs = jnp.asarray(rng.random((300, 3)).astype(np.float32))
+    params = SearchParams(radius=0.11, k=8, knn_window="exact")
+    ns = NeighborSearch(pts, params)
+    obs.configure(mode="log")
+    try:
+        h = ns.executor.capture_plan(qs)
+        paths = {s["path"] for s in obs.recent_spans()}
+    finally:
+        obs.configure()
+    assert "plan/sync" in paths
+    launcher = ns.executor._get_launcher(h.groups, h.nq)
+    k = params.k
+    text = launcher.lower(
+        ns.grid, ns.points, qs[h.perm], h.perm, h.sels_dev,
+        jnp.full((h.nq, k), -1, jnp.int32),
+        jnp.full((h.nq, k), jnp.inf, jnp.float32),
+        jnp.zeros((h.nq,), jnp.int32)).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]+)"', text)
+    for stage in ("window_gather", "row_gather", "distance", "select"):
+        assert any(re.search(rf"repro\.execute_plan/.*repro\.search\.{stage}",
+                             n) for n in op_names), stage

@@ -326,3 +326,117 @@ def test_executor_syncs_identical_on_off(rng):
     assert syncs_off == syncs_on == 1
     np.testing.assert_array_equal(np.asarray(res_off.indices),
                                   np.asarray(res_on.indices))
+
+
+# ------------------------------------------- compiles, from JAX's own events
+
+
+def _compile_params():
+    # a signature no other test of this module compiles, so the programs
+    # below are not already in JAX's in-process caches
+    return SearchParams(radius=0.13, k=7, knn_window="exact")
+
+
+def test_session_step_counts_its_compiles(rng):
+    """The first step and a respec step compile their step program; a
+    steady replayed step compiles nothing. Each step's count is on its
+    report, on its ``step`` span and summed in ``stats()``."""
+    obs.configure(mode="log")
+    pts = rng.random((613, 3)).astype(np.float32) * 0.5
+    sess = SimulationSession(pts, _compile_params())
+    sess.step(pts)                                  # capture: compiles
+    first = sess.report.compiles
+    assert first >= 1
+    sess.step(_jitter(rng, pts, 0.0005))            # replay variant
+    sess.step(_jitter(rng, pts, 0.0005))            # steady
+    assert sess.report.fast and sess.report.compiles == 0
+    steps = [s for s in obs.recent_spans() if s["path"] == "step"]
+    assert steps[0]["attrs"]["compiles"] == first
+    assert steps[-1]["attrs"]["compiles"] == 0
+    far = (pts + np.float32([2.0, 0.0, 0.0])).astype(np.float32)
+    sess.step(far)                                  # respec: a new spec
+    assert sess.report.respecced and sess.report.compiles >= 1
+    st = sess.stats()
+    assert st["last"]["compiles"] == sess.report.compiles
+    assert st["compiles"] == sum(s["attrs"]["compiles"] for s in
+                                 obs.recent_spans() if s["path"] == "step")
+    # the compiles come from JAX's event: each is a compile span nested
+    # in the step, and is counted in the registry's compile component
+    paths = [s["path"] for s in obs.recent_spans() if s["name"] == "compile"]
+    assert len([p for p in paths if p.startswith("step/")]) == st["compiles"]
+    agg = obs.REGISTRY.aggregate()["compile"]
+    assert agg["compiles"]["value"] >= st["compiles"]
+    assert agg["compile_s"]["count"] == agg["compiles"]["value"]
+
+
+def test_sharded_session_step_counts_its_compiles(rng):
+    pts = rng.random((617, 3)).astype(np.float32)
+    sess = ShardedSession(pts, _compile_params(), n_slabs=1)
+    sess.step(pts)
+    assert sess.last_compiles >= 1
+    sess.step(_jitter(rng, pts, 0.0005))
+    sess.step(_jitter(rng, pts, 0.0005))
+    assert sess.last_compiles == 0
+    st = sess.stats()
+    assert st["last_compiles"] == 0 and st["compiles"] >= 1
+
+
+def test_executor_query_counts_its_compiles(rng):
+    from repro.core import NeighborSearch
+    pts = rng.random((619, 3)).astype(np.float32)
+    qs = rng.random((131, 3)).astype(np.float32)
+    ns = NeighborSearch(pts, _compile_params())
+    ns.query(qs)
+    assert ns.executor.stats()["last"]["compiles"] >= 1
+    ns.query(qs)
+    assert ns.executor.stats()["last"]["compiles"] == 0
+
+
+def test_compile_counters_follow_jax_events():
+    """The listeners are registered with ``jax.monitoring`` itself: an
+    event recorded there is counted, and survives an ``obs.reset()``."""
+    from jax import monitoring
+    from repro.obs import compiles
+    obs.reset()
+    monitoring.record_event(compiles.CACHE_HIT_EVENT)
+    monitoring.record_event_duration_secs(compiles.BACKEND_COMPILE_EVENT,
+                                          0.25, fun_name="jit(f)")
+    monitoring.record_event_duration_secs("/jax/some/other_duration", 1.0)
+    agg = obs.REGISTRY.aggregate()["compile"]
+    assert agg["compile_cache_hits"]["value"] == 1
+    assert agg["compiles"]["value"] == 1
+    assert agg["compile_s"]["sum"] == 0.25
+    # the process-wide set outlives the eviction of older instance sets
+    from repro.obs import registry
+    for _ in range(registry._LIVE_SETS_MAX + 8):
+        obs.metric_set("executor")
+    monitoring.record_event_duration_secs(compiles.BACKEND_COMPILE_EVENT,
+                                          0.5, fun_name="jit(g)")
+    assert obs.REGISTRY.aggregate()["compile"]["compiles"]["value"] == 2
+
+
+def test_span_start_is_on_the_profiler_clock(tmp_path):
+    """A span's recorded start and the profiler's TraceMe event of the
+    same span agree once the profile's start time is subtracted."""
+    import glob
+
+    from jax.profiler import ProfileData
+    obs.configure(mode="log")
+    jax.profiler.start_trace(str(tmp_path))
+    with obs.span("probe.clock"):
+        pass
+    jax.profiler.stop_trace()
+    rec = next(s for s in obs.recent_spans() if s["name"] == "probe.clock")
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path[0])
+    base = event = None
+    for plane in pd.planes:
+        for name, value in plane.stats:
+            if name == "profile_start_time":
+                base = value
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == "probe.clock":
+                    event = e
+    assert base is not None and event is not None
+    assert abs(rec["t0_s"] * 1e9 - base - event.start_ns) < 1e6

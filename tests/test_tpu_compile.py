@@ -82,19 +82,36 @@ def test_merge_topk_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_query_compiles_for_v5e(one_chip):
-    """jax.jit(api.query) — the default search path, as one program — on a
-    LiDAR-like frame with the smoke's search parameters, within one
-    chip's HBM."""
+@pytest.fixture(scope="module")
+def query_program(one_chip):
+    """jax.jit(api.query) compiled for one v5e chip on a LiDAR-like frame
+    with the smoke's search parameters."""
     import repro.api as api
     from repro.data.pointclouds import kitti_like_cloud
     pts = kitti_like_cloud(32_768, seed=0)
     params = api.SearchParams(radius=0.01, k=16, knn_window="exact")
     index = api.build_index(pts, params)
     shapes = jax.tree.map(lambda x: _shape(x, one_chip), index)
-    compiled = jax.jit(api.query).lower(
-        shapes, _shape(pts, one_chip)).compile()
+    return jax.jit(api.query).lower(shapes, _shape(pts, one_chip)).compile()
+
+
+def test_query_compiles_for_v5e(query_program):
+    """jax.jit(api.query) — the default search path, as one program —
+    within one chip's HBM."""
+    compiled = query_program
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES
+
+
+def test_query_program_carries_the_search_scopes(query_program):
+    """The scopes the device-trace metrics read survive the chip's
+    compiler: each stage of the per-tile search and each ladder level is
+    the op_name of at least one operation of the compiled program."""
+    import re
+    op_names = re.findall(r'op_name="([^"]+)"', query_program.as_text())
+    for stage in ("window_gather", "row_gather", "distance", "select"):
+        assert any(f"repro.search.{stage}" in n for n in op_names), stage
+    assert any(re.search(r"repro\.launch\.level\d+_w\d+", n)
+               for n in op_names)
