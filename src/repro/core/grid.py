@@ -95,12 +95,13 @@ def build_cell_grid(points: Array, spec: GridSpec,
     flat = spec.flat_cell(ccoord)
     if valid is not None:
         flat = jnp.where(valid, flat, spec.num_cells)   # scatter-dropped
-    return _grid_from_flat(flat, points.shape[0], spec)
+    return _grid_from_flat(flat, points, spec)
 
 
-def _grid_from_flat(flat: Array, n: int, spec: GridSpec) -> CellGrid:
-    """Dense grid + counts + SAT from precomputed flat cell ids (shared by
-    the static build and the dynamic update path)."""
+def _grid_from_flat(flat: Array, points: Array, spec: GridSpec) -> CellGrid:
+    """Dense grid + coordinate table + counts + SAT from precomputed flat
+    cell ids (shared by the static build and the dynamic update path)."""
+    n = points.shape[0]
     order = jnp.argsort(flat, stable=True)
     flat_sorted = flat[order]
     # rank within cell = position - first position of this cell id
@@ -119,6 +120,17 @@ def _grid_from_flat(flat: Array, n: int, spec: GridSpec) -> CellGrid:
         .set(jnp.arange(n, dtype=jnp.int32), mode="drop")
         .reshape(dx * dy * dz, spec.capacity)
     )
+    # the same slots' coordinates, interleaved per cell: the x of its C
+    # slots, then their y, then their z (CellGrid.coords); a slot that
+    # holds no point keeps the finite 0.0 (dense marks it -1)
+    cap = spec.capacity
+    base = jnp.where(keep, flat * 3 * cap + rank, 3 * dx * dy * dz * cap)
+    coords = (
+        jnp.zeros((3 * dx * dy * dz * cap,), jnp.float32)
+        .at[jnp.concatenate([base + a * cap for a in range(3)])]
+        .set(points.T.reshape(-1).astype(jnp.float32), mode="drop")
+        .reshape(dx, dy, dz * 3 * cap)
+    )
 
     # mode="drop": rows routed to the out-of-range id num_cells (invalid /
     # parked slots) contribute to no cell
@@ -131,6 +143,7 @@ def _grid_from_flat(flat: Array, n: int, spec: GridSpec) -> CellGrid:
     return CellGrid(
         spec=spec,
         dense=dense.reshape(dx, dy, dz, spec.capacity),
+        coords=coords,
         counts=counts,
         sat=sat,
         overflow=overflow,
@@ -187,7 +200,7 @@ def _update_impl(grid: CellGrid, points: Array, anchor_points: Array,
     flat = spec.flat_cell(ccoord)
     if valid is not None:
         flat = jnp.where(valid, flat, spec.num_cells)
-    new = _grid_from_flat(flat, points.shape[0], spec)
+    new = _grid_from_flat(flat, points, spec)
     stats = UpdateStats(overflow=new.overflow, oob=oob, max_disp2=max_d2)
     return new, stats, ccoord
 

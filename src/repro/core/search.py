@@ -61,9 +61,14 @@ def window_tile_search(
     for the same ``w``/``skip_test`` signature.
 
     Step 1 (paper: ray-AABB on RT cores) is the regular window gather —
-    pure index arithmetic. Step 2 (paper: IS shader sphere test) is the
-    tiled pairwise-distance + bounded-K selection; with ``skip_test`` the
-    r^2 filter is elided (paper's megacell-inscribed range-search case).
+    pure index arithmetic: the window's candidate ids from ``grid.dense``
+    and their coordinates from ``grid.coords``, each as one slice per
+    query. Step 2 (paper: IS shader sphere test) is the tiled
+    pairwise-distance + bounded-K selection; with ``skip_test`` the r^2
+    filter is elided (paper's megacell-inscribed range-search case).
+    ``points`` is not read here: it stays in the searcher signature that
+    ``window_search`` shares with the Pallas path
+    (``NeighborSearch._searcher``).
     """
     # per-axis window, clamped to the grid (thin-slab datasets like KITTI
     # have near-degenerate axes whose whole extent fits inside the window)
@@ -87,8 +92,19 @@ def window_tile_search(
             return blk.reshape(-1)
 
         cand = jax.vmap(gather_one)(start)               # [T, W^3*C]
+    # the same window of the coordinate table, whose minor dimension is
+    # the z-run of cells times their 3 coordinates times their slots
+    # (CellGrid.coords): slot order matches cand's, and an empty slot's
+    # 0.0 is masked by cand < 0
     with jax.named_scope("repro.search.row_gather"):
-        cand_pos = points[jnp.clip(cand, 0, points.shape[0] - 1)]
+        def coords_one(st):
+            blk = jax.lax.dynamic_slice(
+                grid.coords, (st[0], st[1], st[2] * 3 * cap),
+                (ws[0], ws[1], ws[2] * 3 * cap))
+            return blk.reshape(*ws, 3, cap)
+
+        cand_pos = jnp.moveaxis(jax.vmap(coords_one)(start), 4, 1)
+        cand_pos = cand_pos.reshape(qt.shape[0], 3, -1)  # [T, 3, W^3*C]
     with jax.named_scope("repro.search.distance"):
         d2 = _tile_d2(qt, cand_pos)                      # [T, W^3*C]
     with jax.named_scope("repro.search.select"):
@@ -157,11 +173,11 @@ def window_search(
 
 
 def _tile_d2(q: Array, cand_pos: Array) -> Array:
-    """[T, 3] x [T, M, 3] -> [T, M] squared distances (batched MXU form;
-    ``HIGHEST`` keeps f32 products on the TPU too)."""
+    """[T, 3] x [T, 3, M] (planar) -> [T, M] squared distances (batched MXU
+    form; ``HIGHEST`` keeps f32 products on the TPU too)."""
     qn = jnp.sum(q * q, axis=-1, keepdims=True)              # [T, 1]
-    pn = jnp.sum(cand_pos * cand_pos, axis=-1)               # [T, M]
-    cross = jnp.einsum("td,tmd->tm", q, cand_pos,
+    pn = jnp.sum(cand_pos * cand_pos, axis=1)                # [T, M]
+    cross = jnp.einsum("td,tdm->tm", q, cand_pos,
                        precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(qn + pn - 2.0 * cross, 0.0)
 

@@ -71,6 +71,17 @@ class CellGrid:
     """The built acceleration structure.
 
     ``dense``    [Dx, Dy, Dz, C]  int32 point indices, -1 padded.
+    ``coords``   [Dx, Dy, Dz*3*C] f32 coordinate table: for each cell, the
+                 x of its C slots, then their y, then their z, of the point
+                 ``dense`` names there (0.0 in empty slots, which ``dense``
+                 marks -1). A query's window is one ``(wx, wy, wz*3*C)``
+                 slice, at the cells its ids come from in ``dense``, so the
+                 search reads its candidates' coordinates as one window
+                 slice per query, not as one row gather per candidate. The
+                 minor dimension is the run a window reads, cells times
+                 coordinates times slots: a minor dimension of the 3
+                 coordinates pads to 128 lanes on the TPU (DESIGN.md
+                 section 2).
     ``counts``   [Dx, Dy, Dz]     int32 points per cell (clipped to C).
     ``sat``      [Dx+1, Dy+1, Dz+1] int32 3-D summed-area table of counts;
                  box sums in O(1) for the megacell growth of paper section 5.1.
@@ -81,18 +92,20 @@ class CellGrid:
 
     spec: GridSpec
     dense: Array
+    coords: Array
     counts: Array
     sat: Array
     overflow: Array
 
     def tree_flatten(self):
-        return (self.dense, self.counts, self.sat, self.overflow), self.spec
+        return ((self.dense, self.coords, self.counts, self.sat,
+                 self.overflow), self.spec)
 
     @classmethod
     def tree_unflatten(cls, spec, leaves):
-        dense, counts, sat, overflow = leaves
-        return cls(spec=spec, dense=dense, counts=counts, sat=sat,
-                   overflow=overflow)
+        dense, coords, counts, sat, overflow = leaves
+        return cls(spec=spec, dense=dense, coords=coords, counts=counts,
+                   sat=sat, overflow=overflow)
 
 
 @jax.tree_util.register_pytree_node_class

@@ -331,6 +331,47 @@ def test_session_grid_donation_alias_safety(rng):
     _assert_oracle_exact(res, pts, pts, 0.1, 8)
 
 
+def _assert_table_matches(grid, pts):
+    """CellGrid.coords holds each slot's point, 0.0 where dense is -1."""
+    cap = grid.spec.capacity
+    dense = np.asarray(grid.dense).reshape(-1)
+    table = np.moveaxis(np.asarray(grid.coords).reshape(-1, 3, cap), 1,
+                        2).reshape(-1, 3)
+    full = dense >= 0
+    np.testing.assert_array_equal(table[full], np.asarray(pts)[dense[full]])
+    assert (table[~full] == 0.0).all()
+
+
+@pytest.mark.parametrize("donate_grid", [True, False])
+def test_session_coordinate_table_tracks_positions(rng, donate_grid):
+    """The session's grid carries the coordinate table of the current
+    positions after every kind of step — replay, replan, respec — with
+    the grid donated into each step or not."""
+    import warnings
+    pts = rng.random((800, 3)).astype(np.float32)
+    params = SearchParams(radius=0.1, k=8, knn_window="exact")
+    sess = SimulationSession(pts, params,
+                             sopts=SessionOpts(donate_grid=donate_grid))
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")          # CPU donation warning
+        sess.step(pts)
+        _assert_table_matches(sess.index.grid, pts)
+        pts2 = _drift(rng, pts, 0.0003)
+        sess.step(pts2)
+        assert sess.report.fast
+        _assert_table_matches(sess.index.grid, pts2)
+        big = pts2.copy()
+        big[5] += np.float32([sess.spec.cell_size, 0, 0])
+        sess.step(big)
+        assert sess.report.replanned
+        _assert_table_matches(sess.index.grid, big)
+        far = (big + np.float32([4.0, 0, 0])).astype(np.float32)
+        res = sess.step(far)
+        assert sess.report.respecced
+        _assert_table_matches(sess.index.grid, far)
+        _assert_oracle_exact(res, far, far, 0.1, 8)
+
+
 def test_update_cell_grid_matches_fresh_build(rng):
     """The incremental update must produce the bit-identical structure a
     fresh build over the moved points would."""
@@ -346,6 +387,8 @@ def test_update_cell_grid_matches_fresh_build(rng):
                                   np.asarray(fresh.dense))
     np.testing.assert_array_equal(np.asarray(g2.sat),
                                   np.asarray(fresh.sat))
+    np.testing.assert_array_equal(np.asarray(g2.coords),
+                                  np.asarray(fresh.coords))
     np.testing.assert_array_equal(np.asarray(ccoord),
                                   np.asarray(spec.cell_of(
                                       jnp.asarray(moved))))

@@ -1,9 +1,15 @@
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.grid import box_count, build_cell_grid, choose_grid_spec
+from repro.core.grid import (box_count, build_cell_grid, choose_grid_spec,
+                             parked_mask, update_cell_grid,
+                             update_cell_grid_traced)
+from repro.core.types import PARK_SENTINEL
 
 
 def _points(rng, n):
@@ -27,6 +33,60 @@ def test_every_point_in_its_cell(rng):
     for idx in range(0, 500, 37):
         cx, cy, cz = ccoord[idx]
         assert idx in dense[cx, cy, cz], (idx, ccoord[idx])
+
+
+def _table_grid(rng, builder):
+    """(grid, points) from one of the builders that write CellGrid.coords;
+    the update builders step twice, so the second update re-bins a grid
+    that an update wrote."""
+    pts = _points(rng, 600)
+    spec = choose_grid_spec(pts, radius=0.1, capacity_slack=2.0)
+    if builder == "build":
+        return build_cell_grid(jnp.asarray(pts), spec), pts
+    if builder == "parked_valid":
+        pts[::7] = np.float32(PARK_SENTINEL)
+        dev = jnp.asarray(pts)
+        grid = build_cell_grid(dev, spec,
+                               valid=jnp.logical_not(parked_mask(dev)))
+        assert not np.isin(np.arange(0, 600, 7), np.asarray(grid.dense)).any()
+        return grid, pts
+    grid = build_cell_grid(jnp.asarray(pts), spec)
+    for _ in range(2):
+        moved = np.clip(pts + rng.normal(0, 0.01, pts.shape), 0, 1
+                        ).astype(np.float32)
+        if builder == "update_traced":
+            grid, _stats, _cc = jax.jit(update_cell_grid_traced)(
+                grid, jnp.asarray(moved), jnp.asarray(pts))
+        else:
+            with warnings.catch_warnings():
+                warnings.simplefilter("ignore")      # CPU ignores donation
+                grid, _stats, _cc = update_cell_grid(
+                    grid, jnp.asarray(moved), jnp.asarray(pts),
+                    donate=builder == "update_donated")
+        pts = moved
+    return grid, pts
+
+
+@pytest.mark.parametrize("builder", ["build", "parked_valid", "update",
+                                     "update_traced", "update_donated"])
+def test_coordinate_table_holds_each_slots_point(rng, builder):
+    """CellGrid.coords holds, in every slot, the coordinates of the point
+    that ``dense`` names there, and 0.0 in every empty slot, whichever
+    builder wrote it: the fresh build, a build that drops parked rows by
+    ``valid``, and the incremental update (jitted, traced, donated)."""
+    grid, pts = _table_grid(rng, builder)
+    dx, dy, dz = grid.spec.dims
+    cap = grid.spec.capacity
+    assert grid.coords.shape == (dx, dy, dz * 3 * cap)
+    assert grid.coords.dtype == jnp.float32
+    dense = np.asarray(grid.dense).reshape(-1)
+    # per cell: the x of its slots, then their y, then their z
+    table = np.moveaxis(np.asarray(grid.coords).reshape(-1, 3, cap), 1,
+                        2).reshape(-1, 3)
+    full = dense >= 0
+    assert full.any()
+    np.testing.assert_array_equal(table[full], pts[dense[full]])
+    assert (table[~full] == 0.0).all()
 
 
 @given(st.integers(10, 400), st.integers(0, 2**31 - 1))

@@ -2,6 +2,7 @@
 paper's optimization ablation matrix (Fig. 13) and point distributions."""
 import itertools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,6 +10,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import (NeighborSearch, SearchOpts, SearchParams,
                         neighbor_search)
+from repro.core.grid import build_cell_grid, choose_grid_spec
+from repro.core.search import _select, _tile_d2, window_tile_search
 from repro.data.pointclouds import clustered_cloud, kitti_like_cloud, \
     uniform_cloud
 from repro.kernels.ref import brute_force_search
@@ -119,3 +122,87 @@ def test_report_breakdown_populated(rng):
     assert ns.report.num_partitions >= 1
     assert len(ns.report.bundles) >= 1
     assert ns.report.t_search > 0
+
+
+def _row_gather_tile(grid, points, qt, spec, w, radius, k, skip_test):
+    """The search tile with its coordinates read the other way: the same
+    window of candidate ids, then one row gather ``points[cand]`` per
+    candidate, into the same ``_tile_d2`` and ``_select``."""
+    ws = tuple(min(2 * w + 1, d) for d in spec.dims)
+    dims = jnp.asarray(spec.dims, jnp.int32)
+    start = jnp.clip(spec.cell_of(qt) - w, 0,
+                     dims - jnp.asarray(ws, jnp.int32))
+    cand = jax.vmap(lambda st: jax.lax.dynamic_slice(
+        grid.dense, (st[0], st[1], st[2], 0),
+        (*ws, spec.capacity)).reshape(-1))(start)
+    rows = points[jnp.clip(cand, 0, points.shape[0] - 1)]      # [T, M, 3]
+    d2 = _tile_d2(qt, jnp.swapaxes(rows, 1, 2))
+    return _select(d2, cand, k, jnp.float32(radius) ** 2, skip_test)
+
+
+def _tile_scene(rng, scene):
+    """(points, queries, radius, k, w): a uniform knn scene searched over
+    its full-radius window, or a clustered range scene over a narrower
+    window (where the sphere-test skip is what a megacell would allow)."""
+    if scene == "knn":
+        pts = rng.random((1537, 3)).astype(np.float32)
+        return pts, rng.random((256, 3)).astype(np.float32), 0.12, 8, 4
+    pts = clustered_cloud(1537, seed=int(rng.integers(1 << 30)))
+    qs = pts[rng.choice(len(pts), 256, replace=False)]
+    return pts, qs, 0.05, 16, 2
+
+
+@pytest.mark.parametrize("skip_test", [False, True])
+@pytest.mark.parametrize("scene", ["knn", "range"])
+def test_window_tile_search_matches_row_gather(rng, scene, skip_test):
+    """The tile that reads its candidates' coordinates from the grid's
+    coordinate table returns what the per-candidate row gather of the
+    points returns: counts exact, d2 within 1e-6, ids equal up to ties
+    at the K-th place."""
+    pts, qs, r, k, w = _tile_scene(rng, scene)
+    spec = choose_grid_spec(pts, r)
+    grid = build_cell_grid(jnp.asarray(pts), spec)
+    args = (grid, jnp.asarray(pts), jnp.asarray(qs))
+    d2a, ia, ca = jax.jit(lambda g, p, q: window_tile_search(
+        g, p, q, spec, w, r, k, skip_test))(*args)
+    d2b, ib, cb = jax.jit(lambda g, p, q: _row_gather_tile(
+        g, p, q, spec, w, r, k, skip_test))(*args)
+    ca, cb = np.asarray(ca), np.asarray(cb)
+    np.testing.assert_array_equal(ca, cb)
+    assert ca.max() > 0
+    da = np.where(np.isinf(d2a), -1.0, np.asarray(d2a))
+    db = np.where(np.isinf(d2b), -1.0, np.asarray(d2b))
+    np.testing.assert_allclose(da, db, rtol=0, atol=1e-6)
+    ia, ib = np.asarray(ia), np.asarray(ib)
+    for row in np.flatnonzero((np.sort(ia, 1) != np.sort(ib, 1)).any(1)):
+        kth = da[row, ca[row] - 1]
+        assert (np.abs(da[row][~np.isin(ia[row], ib[row])] - kth)
+                <= 1e-6).all(), row
+        assert (np.abs(db[row][~np.isin(ib[row], ia[row])] - kth)
+                <= 1e-6).all(), row
+
+
+def test_window_tile_search_reads_no_point_rows(rng):
+    """The tile's jaxpr holds no gather from the points: candidates'
+    coordinates come as window slices of the coordinate table."""
+    pts, qs, r, k, w = _tile_scene(rng, "knn")
+    spec = choose_grid_spec(pts, r)
+    grid = build_cell_grid(jnp.asarray(pts), spec)
+    closed = jax.make_jaxpr(lambda g, p, q: window_tile_search(
+        g, p, q, spec, w, r, k, False))(grid, jnp.asarray(pts),
+                                        jnp.asarray(qs))
+    points_var = closed.jaxpr.invars[len(jax.tree.leaves(grid))]
+    assert points_var.aval.shape == pts.shape
+
+    def gathers(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "gather":
+                yield eqn.invars[0]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from gathers(sub)
+
+    operands = list(gathers(closed.jaxpr))
+    assert operands                     # the window slices are gathers
+    # by identity at the top level, by shape inside nested jaxprs
+    assert not any(v is points_var or v.aval.shape == pts.shape
+                   for v in operands)
