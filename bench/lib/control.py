@@ -11,6 +11,8 @@ decides ``correct`` has to fail it.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,6 +41,20 @@ def _cross(q, p, precision: str):
     raise ValueError(f"unknown control precision {precision!r}")
 
 
+@partial(jax.jit, static_argnames=("k", "precision"))
+def _block(qb, p, pn, r2, k: int, precision: str):
+    """One block of queries over every point; the points are an argument,
+    not a constant, so that every block and every unit of one shape runs
+    one compiled program."""
+    d2 = (jnp.sum(qb * qb, axis=-1, keepdims=True) + pn[None, :]
+          - 2.0 * _cross(qb, p, precision))
+    d2 = jnp.where(d2 <= r2, jnp.maximum(d2, 0.0), jnp.inf)
+    neg, sel = jax.lax.top_k(-d2, k)
+    ok = jnp.isfinite(neg)
+    return (jnp.where(ok, sel, -1), jnp.where(ok, -neg, jnp.inf),
+            jnp.sum(ok, axis=-1))
+
+
 def brute_force(points, queries, radius: float, k: int, precision: str,
                 block: int = 256):
     """Bounded-K nearest in-range points of each query over every point:
@@ -46,21 +62,11 @@ def brute_force(points, queries, radius: float, k: int, precision: str,
     p = jnp.asarray(points, jnp.float32)
     pn = jnp.sum(p * p, axis=-1)
     r2 = jnp.float32(radius) ** 2
-
-    @jax.jit
-    def one(qb):
-        d2 = (jnp.sum(qb * qb, axis=-1, keepdims=True) + pn[None, :]
-              - 2.0 * _cross(qb, p, precision))
-        d2 = jnp.where(d2 <= r2, jnp.maximum(d2, 0.0), jnp.inf)
-        neg, sel = jax.lax.top_k(-d2, k)
-        ok = jnp.isfinite(neg)
-        return (jnp.where(ok, sel, -1), jnp.where(ok, -neg, jnp.inf),
-                jnp.sum(ok, axis=-1))
-
     q = np.asarray(queries, np.float32)
     pad = (-len(q)) % block
     qp = np.concatenate([q, np.repeat(q[-1:], pad, axis=0)])
-    outs = [jax.device_get(one(jnp.asarray(qp[s:s + block])))
+    outs = [jax.device_get(_block(jnp.asarray(qp[s:s + block]), p, pn, r2,
+                                  k, precision))
             for s in range(0, len(qp), block)]
     idx, d2, cnt = (np.concatenate(a)[:len(q)] for a in zip(*outs))
     return idx, d2, cnt

@@ -32,21 +32,31 @@ class RunInfo:
     setup_s: float
     window_s: float          # host clock, timed or traced window
     units: int               # whole frames/steps in that window
-    sizes: tuple             # (points, queries, K) of one unit
+    sizes: tuple             # (points, queries, K) of one unit, queries
+                             # the mean over the window's units
     neighbours: float        # mean returned neighbours per unit
     device_kind: str
     trace: object = None     # bench.lib.trace.Trace of a traced run
+    unit_s: list = dataclasses.field(default_factory=list)
+    # host seconds of each unit of the window, in order
+    notes: dict = dataclasses.field(default_factory=dict)
+    # the loop's counts of the window (``LoopBase.notes``)
+
+
+def _timed_unit(loop, unit_s: list) -> None:
+    t0 = time.perf_counter()
+    loop.unit()
+    unit_s.append(time.perf_counter() - t0)
 
 
 def _timed_window(loop, seconds: float):
+    unit_s = []
     t0 = time.perf_counter()
-    n = 0
     while True:
-        loop.unit()
-        n += 1
+        _timed_unit(loop, unit_s)
         elapsed = time.perf_counter() - t0
         if elapsed >= seconds:
-            return elapsed, n
+            return elapsed, unit_s
 
 
 def _traced_window(loop, units: int, trace_dir):
@@ -60,15 +70,16 @@ def _traced_window(loop, units: int, trace_dir):
     options.python_tracer_level = 0
     options.enable_hlo_proto = True     # the scopes of the operations
     jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    unit_s = []
     try:
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.window"):
             for _ in range(units):
-                loop.unit()
+                _timed_unit(loop, unit_s)
         elapsed = time.perf_counter() - t0
     finally:
         jax.profiler.stop_trace()
-    return elapsed, units, trace_mod.read_dir(trace_dir)
+    return elapsed, unit_s, trace_mod.read_dir(trace_dir)
 
 
 def program_rows(rec, rows):
@@ -78,28 +89,40 @@ def program_rows(rec, rows):
                                           res.counts)]
 
 
+def queries_of(rec):
+    """The queries that a unit's result rows answer, on the host: the
+    record's own, or its points where the unit self-queried."""
+    return np.asarray(rec.points if rec.queries is None else rec.queries)
+
+
 def compare(loop, config: dict, seed: int, answers=program_rows):
     """The numbers compared, each beside its limit, and the units that
-    failed. A sample of rows of every timed unit, drawn from the seed, is
-    judged by the float64 oracle over that unit's own points. ``answers``
-    gives the rows judged (the control puts the reference in the
-    program's place there)."""
+    failed. A sample of rows of every timed unit, drawn from the seed over
+    that unit's queries, is judged by the float64 oracle over that unit's
+    own points. ``answers`` gives the rows judged (the control puts the
+    reference in the program's place there)."""
     limit = float(config["limits"]["d2_err_max"])
     recs = loop.records
     per_unit = max(1, math.ceil(int(config["sample_rows"]) / len(recs)))
     wrong, err_max, overflow, failed, examples = 0, 0.0, 0, 0, []
     s = config["search"]
     for u, rec in enumerate(recs):
-        pts = np.asarray(rec.points)
+        pts, qs = np.asarray(rec.points), queries_of(rec)
         rows = np.sort(rng_for(seed, 3, u).choice(
-            len(pts), min(per_unit, len(pts)), replace=False))
-        got = answers(rec, rows)
-        oracle = Oracle(pts, pts[rows], s["radius"], s["k"], s["mode"],
-                        band=limit)
-        verdicts, err = oracle.check(*got)
-        bad = [f"unit {u} row {int(rows[i])}: {why}"
-               for i, why in enumerate(verdicts) if why is not None]
+            len(qs), min(per_unit, len(qs)), replace=False))
         ovf = 0 if rec.overflow is None else int(np.asarray(rec.overflow))
+        n_answers = int(rec.result.counts.shape[0])
+        if n_answers != len(qs):
+            # answers to other queries than these: every row is wrong
+            bad = [f"unit {u}: {n_answers} answers for {len(qs)} "
+                   f"queries"] * len(rows)
+            err = 0.0
+        else:
+            oracle = Oracle(pts, qs[rows], s["radius"], s["k"], s["mode"],
+                            band=limit)
+            verdicts, err = oracle.check(*answers(rec, rows))
+            bad = [f"unit {u} row {int(rows[i])}: {why}"
+                   for i, why in enumerate(verdicts) if why is not None]
         wrong += len(bad)
         err_max = max(err_max, err)
         overflow += ovf
@@ -129,12 +152,13 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool, t_start: float,
         loop.setup()
         setup_s = time.perf_counter() - t_start
         if trace:
-            window_s, units, tr = _traced_window(
+            window_s, unit_s, tr = _traced_window(
                 loop, int(cell.traffic["trace_units"]), trace_dir)
         else:
-            window_s, units = _timed_window(loop, seconds)
+            window_s, unit_s = _timed_window(loop, seconds)
     finally:
         loop.close()
+    units = len(unit_s)
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devices)
     notes = loop.notes()
@@ -147,7 +171,8 @@ def run_cell(cell, *, seed: int, seconds: float, trace: bool, t_start: float,
     sizes = loop.sizes()
     info = RunInfo(setup_s=setup_s, window_s=window_s, units=units,
                    sizes=sizes, neighbours=neighbours,
-                   device_kind=devices[0].device_kind, trace=tr)
+                   device_kind=devices[0].device_kind, trace=tr,
+                   unit_s=unit_s, notes=notes)
     checks, failed = compare(loop, cell.config, seed)
     loop.records.clear()
 

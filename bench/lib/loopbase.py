@@ -1,6 +1,6 @@
 """What every traffic loop shares: the scene and the search parameters
-of its configuration, and the record of each timed unit (a frame or a
-step) that the comparison reads once the window has closed."""
+of its configuration, and the record of each timed unit (a frame, a
+step or a call) that the comparison reads once the window has closed."""
 from __future__ import annotations
 
 import dataclasses
@@ -14,11 +14,13 @@ class Record:
     """One unit's points (host or device), its search result as the
     timed path produced it, and, where the path drops what its grid
     cannot hold, the points dropped (a device scalar); None where the
-    path recovers them itself."""
+    path recovers them itself. ``queries`` are the rows the result
+    answers, where they are not the unit's own points."""
 
     points: object
     result: object
     overflow: object = None
+    queries: object = None
 
 
 class LoopBase:
@@ -43,8 +45,19 @@ class LoopBase:
         return self._generate(rng_for(self.seed, *stream),
                               **{**self._scene_args, **overrides})
 
+    def plan_spec(self):
+        """A frozen grid spec, planned by the program's policy for a grid
+        that outlasts many frames (``repro.core.session_grid_spec``) over
+        the sensor's calibration frame, drawn from the configuration's
+        ``calibration_stream``: the same for every seed."""
+        from repro.core import session_grid_spec
+        frame = self._generate(
+            rng_for(*self.config["calibration_stream"]), **self._scene_args)
+        return session_grid_spec(frame, self.params.radius)
+
     def sizes(self) -> tuple[int, int, int]:
-        """(points, queries, K) of one unit: every loop here self-queries."""
+        """(points, queries, K) of one unit; here, of a loop whose units
+        self-query, so that its queries are its points."""
         n = int(self.records[0].points.shape[0])
         return n, n, self.params.k
 

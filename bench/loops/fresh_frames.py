@@ -16,13 +16,10 @@ cache.
 """
 from __future__ import annotations
 
-import queue
-import threading
-
 import jax
 
 from bench.lib.loopbase import LoopBase, Record
-from bench.lib.rng import rng_for
+from bench.lib.producer import Producer
 
 
 def frame_program(params, spec):
@@ -39,35 +36,7 @@ def frame_program(params, spec):
 
 class Loop(LoopBase):
     unit_name = "frame"
-
-    def __init__(self, config, traffic, seed):
-        super().__init__(config, traffic, seed)
-        self._queue = queue.Queue(maxsize=int(traffic["queue_depth"]))
-        self._stop = threading.Event()
-        self._next = 0
-        self.waits = 0
-        self._producer = threading.Thread(target=self._produce,
-                                          name="bench-frames", daemon=True)
-
-    def _produce(self):
-        i = 0
-        while not self._stop.is_set():
-            pts = self.scene(i)
-            while not self._stop.is_set():
-                try:
-                    self._queue.put((i, pts), timeout=0.1)
-                    break
-                except queue.Full:
-                    continue
-            i += 1
-
-    def plan_spec(self):
-        """The frozen spec, planned by the program over the calibration
-        frame, drawn from the configuration's ``calibration_stream``."""
-        from repro.core import session_grid_spec
-        frame = self._generate(
-            rng_for(*self.config["calibration_stream"]), **self._scene_args)
-        return session_grid_spec(frame, self.params.radius)
+    _frames = None
 
     def build_program(self):
         return jax.jit(frame_program(self.params, self.spec))
@@ -75,17 +44,16 @@ class Loop(LoopBase):
     def setup(self):
         self.spec = self.plan_spec()
         self._program = self.build_program()
-        self._producer.start()
+        self._frames = Producer(self.scene, self.traffic["queue_depth"],
+                                "bench-frames")
         for _ in range(int(self.traffic["warm_units"])):
             self._run_frame()
         self.records.clear()
-        self.waits = 0
+        self._frames.waits = 0
 
     def _run_frame(self):
         with jax.profiler.TraceAnnotation("bench.dequeue"):
-            if self._queue.empty():
-                self.waits += 1
-            i, pts = self._queue.get()
+            pts = self._frames.take()
         with jax.profiler.TraceAnnotation("bench.transfer"):
             dev = jax.device_put(pts)
         with jax.profiler.TraceAnnotation("bench.program"):
@@ -100,17 +68,10 @@ class Loop(LoopBase):
             self._run_frame()
 
     def notes(self):
-        return {"producer_waits": self.waits,
+        return {"producer_waits": self._frames.waits,
                 "grid_capacity": self.spec.capacity,
                 "grid_dims": list(self.spec.dims)}
 
     def close(self):
-        self._stop.set()
-        while True:
-            try:
-                self._queue.get_nowait()
-            except queue.Empty:
-                break
-        self._producer.join(timeout=30)
-        if self._producer.is_alive():
-            raise RuntimeError("the frame producer did not stop")
+        if self._frames is not None:
+            self._frames.close()

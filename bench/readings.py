@@ -49,9 +49,9 @@ def main(argv=None) -> int:
 
     def control_rows(rec, rows):
         import numpy as np
-        pts = np.asarray(rec.points)
-        return brute_force(pts, pts[rows], s["radius"], s["k"],
-                           "high")
+        return brute_force(np.asarray(rec.points),
+                           harness.queries_of(rec)[rows], s["radius"],
+                           s["k"], "high")
 
     out = {"program": [], "control": []}
     for kind, seeds in (("program", args.seeds),
@@ -65,12 +65,13 @@ def main(argv=None) -> int:
                     loop.unit()
             finally:
                 loop.close()
+            notes = loop.notes()
             answers = (harness.program_rows if kind == "program"
                        else control_rows)
             checks, failed = harness.compare(loop, cell.config, seed,
                                              answers)
             row = {"kind": kind, "seed": seed, "units": args.units,
-                   "failed": failed, "notes": loop.notes(),
+                   "failed": failed, "notes": notes,
                    **{k: c["value"] for k, c in checks.items()}}
             out[kind].append(row)
             print(json.dumps(row), flush=True)

@@ -69,12 +69,15 @@ def test_rehearsal_prints_the_result_line(workload):
 @pytest.mark.parametrize("workload", CELLS)
 def test_traced_rehearsal_leaves_out_what_it_cannot_read(workload):
     # the CPU has no TPU device plane: every device metric finds nothing
-    # and is left out, never printed as 0
+    # and is left out, never printed as 0; the program's host spans and
+    # counters are there to read
     rc, out, err = run(workload, "--rehearse", trace=1)
     assert rc == 0, err
     line = json.loads(out.strip().splitlines()[-1])
     assert line["correct"] is True
-    assert line["metrics"] == {}
+    cell = names.resolve(BENCH, workload)
+    assert set(line["metrics"]) == {m["name"] for m in cell.per_layer
+                                    if m["source"] != "device_trace"}
     assert {"busy_s", "window_s"} <= set(line["device"])
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
 
@@ -124,17 +127,30 @@ def _altered(res):
                      distances2=res.distances2, counts=res.counts)
 
 
-class _Session:
-    """A session whose results are wrapped by ``fault``."""
+class _Wrapped:
+    """``inner``, with the results of its method ``method`` wrapped by
+    ``fault``."""
 
-    def __init__(self, inner, fault):
-        self._inner, self._fault = inner, fault
-
-    def step(self, points):
-        return self._fault(self._inner.step(points))
+    def __init__(self, inner, method, fault):
+        self._inner = inner
+        setattr(self, method, lambda *a: fault(getattr(inner, method)(*a)))
 
     def __getattr__(self, name):
         return getattr(self._inner, name)
+
+
+def _wrap_system(mod, factory, method, fault):
+    """Make ``mod.<factory>`` build the system under test with ``fault``
+    planted under its ``method``."""
+    make = getattr(mod, factory)
+
+    def broken(*a):
+        inner = make(*a)
+        if fault == "stale":
+            return _Wrapped(inner, method, _stale(lambda r: r))
+        return _Wrapped(inner, method, _half_missing if fault == "half"
+                        else _altered)
+    setattr(mod, factory, broken)
 
 
 def _plant(monkeypatch, fault):
@@ -155,15 +171,11 @@ def _plant(monkeypatch, fault):
                 return lambda pts: (lambda r, o: (wrap(r), o))(*prog(pts))
             mod.Loop.build_program = broken
         elif name == "session_steps":
-            make = mod.make_session
-
-            def broken_session(points, params):
-                inner = make(points, params)
-                if fault == "stale":
-                    return _Session(inner, _stale(lambda r: r))
-                return _Session(inner, _half_missing if fault == "half"
-                                else _altered)
-            mod.make_session = broken_session
+            _wrap_system(mod, "make_session", "step", fault)
+        elif name == "resident_queries":
+            _wrap_system(mod, "make_search", "query", fault)
+        else:
+            raise AssertionError(f"no fault planter for loop {name}")
         return mod
     monkeypatch.setattr(names, "load_module", load)
 

@@ -143,24 +143,29 @@ def test_recorded_chip_trace():
     assert {name for name, _ in gaps} <= {s[0] for s in tr.host_spans}
 
 
-def _run_info(trace):
+def _run_info(trace, notes=None):
     from bench.lib.harness import RunInfo
     return RunInfo(setup_s=12.5, window_s=9.5, units=2,
                    sizes=(120_000, 120_000, 16), neighbours=1.6e6,
-                   device_kind="TPU v5 lite", trace=trace)
+                   device_kind="TPU v5 lite", trace=trace,
+                   notes=notes or {})
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
 def test_every_per_layer_metric_reads_the_trace(metric):
     """Each metric file's reducer reads a number from a trace whose
-    operations carry the program's scopes, and nothing (None, never 0)
-    where the scopes it names ran nothing."""
+    operations carry the program's scopes and whose host spans and
+    window counts are the program's, and nothing (None, never 0) where
+    the scopes it names ran nothing."""
     from bench.lib import names
     read = names.metric_reader(metric)
     tr = synthetic()
     tr.hlo_ops += [["fusion.9", "jit(f)/repro.update_index/scatter", 0.25],
                    ["fusion.8", "jit(f)/repro.plan_query/sort", 0.125]]
-    value = read(_run_info(tr))
+    tr.host_spans += [["query", 1.0, 6.0], ["plan", 1.0, 2.0],
+                      ["sync", 1.5, 1.75], ["launch", 2.0, 2.5],
+                      ["sync", 5.0, 6.0]]
+    value = read(_run_info(tr, notes={"compiles": 1}))
     assert value is not None and value > 0
     bare = synthetic()
     bare.hlo_ops = [[o[0], "", o[2]] for o in bare.hlo_ops]
