@@ -11,6 +11,9 @@ search is a traceable JAX value, so
     one program with zero mid-trace host syncs;
   * ``jax.vmap(query)`` over a stacked batch of same-spec scenes IS
     multi-scene batching (the ROADMAP's "multi-session batching" item);
+  * with no radius (``SearchParams(radius=math.inf)``) the index is the
+    points alone, one cloud [N, 3] or a batch of clouds [B, N, 3], and
+    every query searches its whole cloud: exact kNN, one program a batch;
   * ``shard_map`` over stacked scene leaves distributes it;
   * ``lax.cond`` over ``update_index`` + ``plan_query``/``execute_plan``
     is the dynamic session's device-resident staleness branch
@@ -49,6 +52,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
+import math
 import os
 
 import jax
@@ -61,7 +65,7 @@ from .grid import (build_cell_grid, choose_grid_spec, parked_mask,
 from .partition import (MegacellStatics, compute_megacells, launch_signatures,
                         megacell_statics, signature_levels)
 from .schedule import schedule_by_level
-from .search import window_tile_search
+from .search import cloud_tile_search, window_tile_search
 from .types import (PARK_THRESHOLD, Array, CellGrid, GridSpec, SearchOpts,
                     SearchParams, SearchResult, UpdateStats)
 
@@ -81,6 +85,10 @@ class NeighborIndex:
     sharded slabs (``core/shards.py``) share ONE static spec across the
     mesh while each slab's local frame differs, so the frame must be a
     leaf, not aux (None = use the static ``spec.origin``).
+
+    With no radius (``SearchParams(radius=math.inf)``) there is no grid:
+    ``grid`` and ``statics`` are None and ``points`` is one cloud [N, 3]
+    or a batch of clouds [B, N, 3].
     """
 
     params: SearchParams
@@ -169,7 +177,14 @@ def build_index(points, params: SearchParams,
     ``SearchOpts(use_pallas=True)`` raises ``NotImplementedError`` on a
     TPU backend, where the fused kernel's in-kernel gathers do not compile
     (``kernels/ops.check_fused_path``).
+
+    With no radius (``SearchParams(radius=math.inf)``) no grid is built:
+    ``points`` is one cloud [N, 3] or a batch of clouds [B, N, 3], and
+    every query searches its whole cloud (DESIGN.md section 2). That
+    index takes no ``spec``, ``origin``, parked rows or fused path.
     """
+    if not params.bounded:
+        return _whole_cloud_index(points, params, opts, spec, origin)
     if opts.use_pallas:
         from ..kernels.ops import check_fused_path
         check_fused_path()
@@ -197,6 +212,21 @@ def build_index(points, params: SearchParams,
                              origin=origin)
 
 
+def _whole_cloud_index(points, params, opts, spec, origin) -> NeighborIndex:
+    if (spec is not None or origin is not None or opts.use_pallas
+            or opts.mask_parked):
+        raise ValueError("exact kNN with no radius searches each query's "
+                         "whole cloud: it takes no grid spec, origin, "
+                         "parked rows or fused path")
+    if np.ndim(points) not in (2, 3):
+        raise ValueError(f"points are one cloud [N, 3] or a batch of "
+                         f"clouds [B, N, 3], got {np.shape(points)}")
+    with jax.named_scope("repro.build_index"):
+        points = jnp.asarray(points, jnp.float32)
+        return NeighborIndex(params=params, opts=opts, statics=None,
+                             points=points, grid=None, anchor_points=points)
+
+
 def update_index(index: NeighborIndex,
                  new_points) -> tuple[NeighborIndex, UpdateStats]:
     """Re-bin moved points into the index's frozen spec (pure, traceable).
@@ -209,6 +239,9 @@ def update_index(index: NeighborIndex,
     the replan branch's job (``with_anchor``), typically under the
     session's ``lax.cond``.
     """
+    if not index.params.bounded:
+        raise ValueError("update_index re-bins points into a grid; an "
+                         "index with no radius has none: build it anew")
     with jax.named_scope("repro.update_index"):
         pts = jnp.asarray(new_points, jnp.float32)
         grid, stats, _ccoord = update_cell_grid_traced(
@@ -233,10 +266,27 @@ def plan_query(index: NeighborIndex, queries, *,
     skip is revoked for any window pushed past the inscribed ring — so a
     captured plan stays exact while drift remains under the session
     threshold.
+
+    With no radius the plan is the queries in their own order, tiled, on
+    an empty ladder: every tile searches whole clouds. Over a batch index
+    ``queries`` is [B, Nq, 3], each cloud's own queries, numbered cloud by
+    cloud.
     """
     with jax.named_scope("repro.plan_query"):
         queries = jnp.asarray(queries, jnp.float32)
         params, opts, statics = index.params, index.opts, index.statics
+        if not params.bounded:
+            if queries.shape[:-2] != index.points.shape[:-2]:
+                raise ValueError(
+                    f"queries {queries.shape} do not match the index's "
+                    f"clouds {index.points.shape}")
+            nq = math.prod(queries.shape[:-1])
+            perm = jnp.minimum(jnp.arange(nq + (-nq) % opts.query_tile),
+                               nq - 1).astype(jnp.int32)
+            return QueryPlan(nq=nq, tile=opts.query_tile, ladder=(),
+                             perm=perm, tile_levels=jnp.zeros(
+                                 (perm.shape[0] // opts.query_tile,),
+                                 jnp.int32))
         spec = index.spec
         nq = queries.shape[0]
         tile = opts.query_tile
@@ -292,7 +342,30 @@ def execute_plan(index: NeighborIndex, queries,
     device and the whole call is one traced program.
     """
     with jax.named_scope("repro.execute_plan"):
+        if not index.params.bounded:
+            return _execute_whole_cloud(index, queries, plan)
         return _execute_plan_scoped(index, queries, plan)
+
+
+def _execute_whole_cloud(index, queries, plan):
+    """``execute_plan`` with no radius: one ``lax.map`` over query tiles,
+    each query against every point of its own cloud
+    (``search.cloud_tile_search``); the plan's order is the queries' own."""
+    k, nq = index.params.k, plan.nq
+    clouds = index.points.reshape(-1, *index.points.shape[-2:])
+    qs = jnp.asarray(queries, jnp.float32).reshape(-1, 3)[plan.perm]
+    scene = plan.perm // (nq // clouds.shape[0])       # each row's cloud
+
+    def one_tile(args):
+        with jax.named_scope("repro.launch.cloud"):
+            return cloud_tile_search(clouds, *args, k)
+
+    d2t, idxt, cntt = jax.lax.map(one_tile, (qs.reshape(-1, plan.tile, 3),
+                                             scene.reshape(-1, plan.tile)))
+    lead = index.points.shape[:-2] + (-1,)
+    return SearchResult(indices=idxt.reshape(-1, k)[:nq].reshape(*lead, k),
+                        distances2=d2t.reshape(-1, k)[:nq].reshape(*lead, k),
+                        counts=cntt.reshape(-1)[:nq].reshape(lead))
 
 
 def _execute_plan_scoped(index, queries, plan):

@@ -287,6 +287,11 @@ class SimulationSession:
         spec: GridSpec | None = None,
     ):
         validate_session_opts(sopts)
+        if not params.bounded:
+            raise ValueError(
+                "a SimulationSession needs a finite radius: its staleness "
+                "contract counts drift in cells of a radius; exact kNN "
+                "with no radius is served by repro.api")
         self.sopts = sopts
         pts = jnp.asarray(points, jnp.float32)
         spec = spec or session_grid_spec(

@@ -111,6 +111,26 @@ def window_tile_search(
         return _select(d2, cand, k, r2, skip_test)
 
 
+def cloud_tile_search(clouds: Array, qt: Array, scene: Array,
+                      k: int) -> tuple[Array, Array, Array]:
+    """One query tile ``qt`` [T, 3] against every point of its own cloud:
+    ([T, k] d2, [T, k] idx, [T] cnt), exact kNN with no radius.
+
+    ``clouds`` is [B, N, 3] and ``scene`` [T] names each query's cloud;
+    its N points are the candidates, in index order. Distances and
+    selection are ``window_tile_search``'s.
+    """
+    with jax.named_scope("repro.search.cloud_gather"):
+        cand_pos = jnp.swapaxes(clouds, 1, 2)[scene]       # [T, 3, N]
+        n = clouds.shape[1]
+        cand = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32),
+                                (qt.shape[0], n))
+    with jax.named_scope("repro.search.distance"):
+        d2 = _tile_d2(qt, cand_pos)                      # [T, N]
+    with jax.named_scope("repro.search.select"):
+        return _select(d2, cand, k, jnp.float32(jnp.inf), False)
+
+
 def _select(d2: Array, cand: Array, k: int, r2, skip_test: bool):
     """Mask the out-of-range and empty candidates, then keep the ``k``
     nearest: ([T, k] d2, [T, k] idx, [T] cnt)."""
@@ -222,6 +242,11 @@ class NeighborSearch:
         cost_model: bundle_mod.CostModel | None = None,
     ):
         from .api import build_index
+        if not params.bounded:
+            raise ValueError(
+                "NeighborSearch needs a finite radius: its executor plans "
+                "windows of a radius; exact kNN with no radius is served "
+                "by repro.api (build_index / query)")
         self.params = params
         self.opts = opts
         self.cost_model = cost_model or bundle_mod.CostModel()

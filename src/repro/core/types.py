@@ -8,6 +8,7 @@ data-dependent (point positions, counts, permutations) lives in arrays.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any
 
@@ -138,12 +139,29 @@ class UpdateStats:
 
 @dataclasses.dataclass(frozen=True)
 class SearchParams:
-    """Static parameters of one neighbor search call."""
+    """Static parameters of one neighbor search call.
+
+    ``radius=math.inf`` is exact kNN with no radius (knn mode only): every
+    query gets the min(K, N) nearest points of its own cloud, found by
+    searching the whole cloud, with no grid; ``knn_window`` is not read
+    (DESIGN.md section 2)."""
 
     radius: float
     k: int
     mode: str = "knn"                  # "knn" | "range"
     knn_window: str = "heuristic"      # "heuristic" | "exact" (paper 5.1)
+
+    def __post_init__(self):
+        if math.isinf(self.radius) and self.mode != "knn":
+            raise ValueError(
+                f"mode={self.mode!r} needs a finite radius: a range search "
+                f"with no radius would return every point; use mode='knn' "
+                f"for exact kNN with radius=math.inf")
+
+    @property
+    def bounded(self) -> bool:
+        """False for exact kNN with no radius (``radius=math.inf``)."""
+        return not math.isinf(self.radius)
 
 
 @jax.tree_util.register_pytree_node_class

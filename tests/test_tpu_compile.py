@@ -115,3 +115,27 @@ def test_query_program_carries_the_search_scopes(query_program):
         assert any(f"repro.search.{stage}" in n for n in op_names), stage
     assert any(re.search(r"repro\.launch\.level\d+_w\d+", n)
                for n in op_names)
+
+
+
+def test_no_radius_batch_query_compiles_for_v5e(one_chip):
+    """jax.jit(api.query) over DGCNN's batch of 32 clouds of 1,024 points
+    with no radius (k=20), as one program for one v5e chip: the
+    whole-cloud search compiles within one chip's HBM, and its scopes
+    survive the compiler."""
+    import math
+    import re
+
+    import repro.api as api
+    pts = np.random.default_rng(0).random((32, 1024, 3), dtype=np.float32)
+    index = api.build_index(pts, api.SearchParams(radius=math.inf, k=20))
+    shapes = jax.tree.map(lambda x: _shape(x, one_chip), index)
+    compiled = jax.jit(api.query).lower(shapes,
+                                        _shape(pts, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert 0 < (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes) < V5E_HBM_BYTES
+    op_names = re.findall(r'op_name="([^"]+)"', compiled.as_text())
+    for scope in ("launch.cloud", "search.cloud_gather", "search.distance",
+                  "search.select"):
+        assert any(f"repro.{scope}" in n for n in op_names), scope
